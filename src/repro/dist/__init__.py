@@ -25,7 +25,6 @@ from .collectives import (
     decompress_grad_int8,
     flatten_grads,
     psum_partial,
-    shard_map_compat,
     unflatten_grads,
     weighted_all_reduce,
 )
@@ -46,7 +45,6 @@ __all__ = [
     "opt_specs",
     "param_specs",
     "psum_partial",
-    "shard_map_compat",
     "unflatten_grads",
     "weighted_all_reduce",
 ]

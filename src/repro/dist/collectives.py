@@ -33,32 +33,7 @@ import numpy as np
 __all__ = ["weighted_all_reduce", "psum_partial", "all_reduce_grads",
            "constrain_grad", "compress_grad_int8", "decompress_grad_int8",
            "BucketLayout", "bucket_layout", "flatten_grads",
-           "unflatten_grads", "BucketedAllReduce", "CompressedBucketSync",
-           "shard_map_compat"]
-
-try:  # moved to jax.shard_map in newer releases
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-except ImportError:  # pragma: no cover - future jax
-    _shard_map_raw = jax.shard_map
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions, replication checking off.
-
-    Two renames straddle the pinned toolchain: the function moved from
-    ``jax.experimental.shard_map`` to ``jax.shard_map``, and the
-    replication-checker flag from ``check_rep`` to ``check_vma``. Every
-    manual program in the repo (the mesh executor's step, the MoE
-    expert-parallel ffn) declares replicated out_specs the checker
-    cannot prove through psum/custom_vjp, so it is disabled under
-    whichever name exists.
-    """
-    try:
-        return _shard_map_raw(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - newer jax
-        return _shard_map_raw(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
+           "unflatten_grads", "BucketedAllReduce", "CompressedBucketSync"]
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -69,7 +44,7 @@ def psum_partial(x: jax.Array, axis_name) -> jax.Array:
     (a local weighted gradient, a local weighted loss): the derivative of
     the global sum w.r.t. a device's partial is exactly 1, so the
     backward pass is the identity. The stock ``lax.psum`` cannot know
-    this — under ``check_rep=False`` its transpose is another ``psum``,
+    this — under ``check_vma=False`` its transpose is another ``psum``,
     which silently multiplies every gradient by the axis size (we
     measured exactly ``dp_degree``x on the first mesh bring-up). Routing
     the §3.1 reduction through this wrapper is what lets
@@ -360,14 +335,22 @@ class CompressedBucketSync:
     #: changes the traced program — strictly an attribution-session knob.
     tel = None
 
+    #: trailing axis of every int8 payload on the wire. The TPU compiler
+    #: takes time linear in B for an all-to-all or all-gather of flat
+    #: int8 chunks, and little for the same bytes in 128-lane rows, so
+    #: each device's chunk must be a whole number of lanes: build the
+    #: layout with ``pad_to=LANES * dp_degree`` (or a multiple).
+    LANES = 128
+
     def __init__(self, layout: BucketLayout, dp_degree: int,
                  axis_name: str, *, fused: bool | None = None):
+        pad = self.LANES * dp_degree
         for b, size in enumerate(layout.bucket_sizes):
-            if size % dp_degree:
+            if size % pad:
                 raise ValueError(
                     f"bucket {b} has {size} elements, not divisible by "
-                    f"dp_degree={dp_degree}; build the layout with "
-                    f"pad_to={dp_degree} (or a multiple)")
+                    f"{self.LANES} * dp_degree={pad}; build the layout "
+                    f"with pad_to={pad} (or a multiple)")
         self.layout = layout
         self.dp = dp_degree
         self.axis_name = axis_name
@@ -398,14 +381,18 @@ class CompressedBucketSync:
     # -- the sync itself (device side, inside shard_map) -------------- #
     def _sync_bucket(self, buf, e1, e2):
         q1, s1, e1_new = compress_grad_int8(buf, e1, fused=self.fused)
-        # ship everyone's partial of my chunk; scales ride separately
-        mine = jax.lax.all_to_all(q1.reshape(self.dp, -1),
-                                  self.axis_name, 0, 0)       # (dp, B/dp)
+        # ship everyone's partial of my chunk; scales ride separately.
+        # int8 payloads move in LANES-wide rows (see LANES)
+        mine = jax.lax.all_to_all(q1.reshape(self.dp, -1, self.LANES),
+                                  self.axis_name, 0, 0
+                                  ).reshape(self.dp, -1)      # (dp, B/dp)
         scales = jax.lax.all_gather(s1, self.axis_name)       # (dp,)
         chunk = jnp.einsum("j,jk->k", scales,
                            mine.astype(jnp.float32))          # fp32 sum
         q2, s2, e2_new = compress_grad_int8(chunk, e2, fused=self.fused)
-        full_q = jax.lax.all_gather(q2, self.axis_name)       # (dp, B/dp)
+        full_q = jax.lax.all_gather(q2.reshape(-1, self.LANES),
+                                    self.axis_name
+                                    ).reshape(self.dp, -1)    # (dp, B/dp)
         full_s = jax.lax.all_gather(s2, self.axis_name)       # (dp,)
         out = (full_q.astype(jnp.float32) * full_s[:, None]).reshape(-1)
         return out, e1_new, e2_new

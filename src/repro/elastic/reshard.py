@@ -4,9 +4,9 @@ Pure helpers under :class:`repro.elastic.ElasticMeshExecutor`:
 
 * :func:`shrink_degree` — the DP degree a survivor set can continue at.
   The new degree must divide the ORIGINAL degree: the executor's bucket
-  layout is padded to the construction-time DP (``bucket_layout(...,
-  pad_to=dp)``), so any divisor still tiles every bucket and the
-  compressed sync's chunk math holds without re-laying-out gradients;
+  layout is padded to a multiple of the construction-time DP degree,
+  so any divisor still tiles every bucket and the compressed sync's
+  chunk math holds without re-laying-out gradients;
 * :func:`survivor_submesh` — the ``(data, model)`` submesh over the kept
   physical data rows of the full mesh;
 * :func:`reshard_tree` — move a pytree onto another mesh's shardings

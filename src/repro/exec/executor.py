@@ -68,8 +68,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.data import spare_batch_rows
 from repro.dist.collectives import (BucketedAllReduce, CompressedBucketSync,
-                                    bucket_layout,
-                                    shard_map_compat as _shard_map)
+                                    bucket_layout)
 from repro.launch.mesh import make_emulated_mesh
 from repro.models.config import ModelConfig
 from repro.obs.trace import maybe_span
@@ -155,7 +154,8 @@ class MeshExecutor(SpareTrainer):
         # bucketed flat sync: the manual program's per-step gradient
         # reduction is O(n_buckets) collectives (fp32 psum, or the int8
         # EF wire protocol), never one per parameter leaf. The layout is
-        # built ONCE, padded to the construction-time DP degree, and
+        # built ONCE, padded to the compressed sync's lane width times the
+        # construction-time DP degree (whole int8 lanes per device), and
         # kept across elastic reshapes: any shrunken data axis that
         # divides the original degree still tiles every bucket, so EF
         # residuals move between meshes bit-transparently (repro.elastic)
@@ -170,7 +170,7 @@ class MeshExecutor(SpareTrainer):
             self._layout = bucket_layout(
                 gtree, max_bucket_elems=max(int(bucket_mb * (1 << 20) // 4),
                                             self.data_degree),
-                pad_to=self.data_degree)
+                pad_to=CompressedBucketSync.LANES * self.data_degree)
         self._bind_mesh(mesh)
         self.params = jax.device_put(self.params, self._pshard)
         self.opt_state = jax.device_put(self.opt_state, self._oshard)
@@ -257,9 +257,12 @@ class MeshExecutor(SpareTrainer):
                 ef = self._grad_sync.state_specs()
                 in_specs.append(ef)
                 out_specs.append(ef)
-            return _shard_map(fn, mesh=self.mesh,
-                              in_specs=tuple(in_specs),
-                              out_specs=tuple(out_specs))
+            # replication checking off: the replicated out_specs rest on
+            # psum_partial's custom VJP, which the checker cannot see
+            return jax.shard_map(fn, mesh=self.mesh,
+                                 in_specs=tuple(in_specs),
+                                 out_specs=tuple(out_specs),
+                                 check_vma=False)
         return fn   # gspmd: sharding comes from jit in/out shardings
 
     def _cache_key(self, s_a: int) -> tuple[int, int, int]:
@@ -509,9 +512,9 @@ class MeshExecutor(SpareTrainer):
                 return sync(g)
 
             if self.sync == "shard_map":
-                fn = _shard_map(grads, mesh=self.mesh,
-                                in_specs=(P(), self._batch_specs()),
-                                out_specs=P())
+                fn = jax.shard_map(grads, mesh=self.mesh,
+                                   in_specs=(P(), self._batch_specs()),
+                                   out_specs=P(), check_vma=False)
                 self._mesh_grad_fn = jax.jit(fn)
             else:
                 self._mesh_grad_fn = jax.jit(

@@ -8,8 +8,9 @@ accumulate, quantize, residual); this kernel fuses the whole pipeline so
 each element is read once per pass:
 
 * pass 1 (``absmax``): one VMEM read of ``grad`` and ``error`` per tile,
-  per-tile ``max |grad + error|`` reductions (the scalar combine across
-  tiles is a trivial host-side ``max``);
+  folded into one resident ``(8, 128)`` running elementwise
+  ``max |grad + error|`` (a full native f32 tile, the smallest output
+  block Mosaic accepts); the final 1024-way ``max`` is one XLA reduce;
 * pass 2 (``quantize``): re-reads the tile once and writes *both* the
   int8 payload and the fp32 residual — the EF accumulate, the rounding,
   and the residual subtraction never leave VMEM.
@@ -35,19 +36,23 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["int8_ef_absmax_kernel", "int8_ef_quantize_kernel",
            "int8_ef_pallas"]
 
-# renamed from TPUCompilerParams across jax releases; unlike the other
-# kernels this one must also run interpret-mode on CPU-only wheels (the
-# tier-1 EF-invariant tests), so resolve whichever name exists
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _INT8_MAX = 127.0
 _LANES = 128
+_SUBLANES = 8
 
 
 def int8_ef_absmax_kernel(x_ref, e_ref, o_ref):
     x = x_ref[...].astype(jnp.float32) + e_ref[...].astype(jnp.float32)
-    o_ref[0, 0] = jnp.max(jnp.abs(x))
+    # fold the tile's rows onto one (8, 128) vreg-shaped partial: max is
+    # exact and order-free, so the result is bit-identical to one global
+    # max over the whole tensor
+    part = jnp.max(jnp.abs(x).reshape(-1, _SUBLANES, _LANES), axis=0)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(part)     # |x| >= 0: the max identity
+
+    o_ref[...] = jnp.maximum(o_ref[...], part)
 
 
 def int8_ef_quantize_kernel(x_ref, e_ref, scale_ref, q_ref, err_ref):
@@ -90,9 +95,10 @@ def int8_ef_pallas(grad: jax.Array, error: jax.Array, *,
         int8_ef_absmax_kernel,
         grid=(n_blocks,),
         in_specs=[tile, tile],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, 1), jnp.float32),
-        compiler_params=_CompilerParams(
+        # one resident accumulator tile across the (sequential) grid
+        out_specs=pl.BlockSpec((_SUBLANES, _LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((_SUBLANES, _LANES), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x2, e2)
@@ -109,7 +115,7 @@ def int8_ef_pallas(grad: jax.Array, error: jax.Array, *,
         out_specs=(tile, tile),
         out_shape=(jax.ShapeDtypeStruct(x2.shape, jnp.int8),
                    jax.ShapeDtypeStruct(x2.shape, jnp.float32)),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, e2, scale.reshape(1, 1))

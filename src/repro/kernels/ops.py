@@ -1,10 +1,15 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites run the
-kernel bodies in Python on CPU (correctness) and compile to Mosaic on a
-real TPU (performance). The model layers call the pure-jnp paths by
-default; these ops are the drop-in hot-path replacements wired in by the
-``use_pallas`` knob of the serving/training drivers on TPU deployments.
+The platform selects the path, never a flag: :func:`on_tpu` is true
+when JAX's default backend is the TPU, and then each wrapper compiles
+its kernel with Mosaic; elsewhere ``interpret`` defaults to True and the
+same kernel body runs in Python (the CPU correctness path).
+
+Only :func:`int8_ef_quantize` has a caller on a production path:
+:func:`repro.dist.collectives.compress_grad_int8` picks it when
+``on_tpu()`` holds (the ``grad_compress="int8_ef"`` mesh sync) and its
+jnp oracle otherwise. The model layers call no kernel here; flash
+attention, the SSD scan and rmsnorm run their pure-jnp spellings.
 """
 from __future__ import annotations
 

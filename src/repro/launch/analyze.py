@@ -1,7 +1,12 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 """Dry-run deep analysis: per-instruction collective/buffer attribution
 with trip-count multipliers — the §Perf hypothesis tool.
+
+A CPU tool by design, like :mod:`repro.launch.dryrun`: the lines above
+pin JAX to the CPU and fan the host out into 512 devices before any jax
+import, so it never takes an accelerator.
 
   python -m repro.launch.analyze --arch qwen2.5-3b --shape train_4k \
       [--multi-pod] [--top 15]

@@ -1,11 +1,14 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run: prove every (arch x shape x mesh) cell lowers,
 SPMD-partitions, compiles, and fits — without touching real hardware.
 
-The two lines above MUST precede any jax import (jax locks the device
-count on first init); smoke tests and benches never import this module,
-so they keep seeing 1 device.
+A CPU tool by design: the lines above pin JAX to the CPU (it never
+takes an accelerator, even on a machine that has one) and fan the host
+out into 512 devices. They MUST precede any jax import (jax locks the
+platform and device count on first init); smoke tests and benches never
+import this module, so they keep seeing 1 device.
 
 Per cell this script:
   1. builds the production mesh (16,16) or (2,16,16);

@@ -22,6 +22,10 @@ deterministic report:
   :class:`~repro.serve.engine.ExecutableCache` program of a warmed
   :class:`~repro.serve.engine.ServeEngine`.
 
+The children are CPU processes by design: each runs with
+``JAX_PLATFORMS=cpu``, so on a machine with an accelerator none of them
+takes the chip.
+
 Exit status: 0 unless ``--assert-clean`` is given and any unsuppressed
 violation survives — the CI ``static-analysis`` job gates on exactly
 that. ``--json`` prints the machine report (byte-identical across
@@ -251,6 +255,7 @@ def _spawn(extra: list[str], out: Path, label: str) -> str | None:
            "--child-out", str(out)]
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)      # each child pins its own device count
+    env["JAX_PLATFORMS"] = "cpu"    # emulated devices, never the chip
     proc = subprocess.run(cmd, env=env, capture_output=True, text=True)
     if proc.returncode != 0 or not out.exists():
         tail = (proc.stderr or proc.stdout or "")[-2000:]

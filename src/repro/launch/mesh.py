@@ -29,31 +29,19 @@ __all__ = ["make_production_mesh", "make_emulated_mesh", "dp_axes",
            "dp_degree"]
 
 
-def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer releases; explicit
-    Auto types match the old default, so fall back silently."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:        # make_mesh predates axis_types
-            pass
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_emulated_mesh(data_degree: int,
                        model_degree: int = 1) -> jax.sharding.Mesh:
-    """``(data, model)`` mesh over the first ``data*model`` local devices.
+    """``(data, model)`` mesh over the first ``data*model`` devices of
+    the default backend — real chips on an accelerator host.
 
-    On a CPU container, export
+    On a CPU-only machine, export ``JAX_PLATFORMS=cpu`` and
     ``XLA_FLAGS=--xla_force_host_platform_device_count=<n>`` *before the
     first jax import* to fan one host out into ``n`` emulated devices —
     the same SPMD partitioner, collectives, and HLO the production mesh
@@ -64,9 +52,10 @@ def make_emulated_mesh(data_degree: int,
     if need > have:
         raise ValueError(
             f"mesh ({data_degree}, {model_degree}) needs {need} devices "
-            f"but only {have} are visible; set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={need} before the "
-            f"first jax import (see README §repro.exec)")
+            f"but only {have} {jax.default_backend()} device(s) are "
+            f"visible; on a CPU-only machine set JAX_PLATFORMS=cpu and "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
+            f"before the first jax import (see README §repro.exec)")
     devices = np.asarray(jax.devices()[:need]).reshape(
         data_degree, model_degree)
     return jax.sharding.Mesh(devices, ("data", "model"))

@@ -1,7 +1,7 @@
 """Serving launcher: continuous-batching decode over SPARe-masked replicas.
 
 ``python -m repro.launch.serve --arch qwen2.5-3b --requests 16`` runs the
-full serving tier end to end on CPU: a deterministic
+full serving tier end to end: a deterministic
 :class:`~repro.data.pipeline.RequestStream` feeds a
 :class:`~repro.serve.replicas.ReplicaServer` (paged KV cache, fused
 prefill, per-slot decode), optionally under a live failure campaign:
@@ -16,6 +16,10 @@ event log; exits non-zero if any admitted request failed to complete
 while a replica survived, or if anything compiled after warmup (the
 SPARe no-recompile gate). ``benchmarks/serving_bench.py`` wraps the same
 loop to record healthy-vs-degraded numbers in ``BENCH_serving.json``.
+
+Model size follows :mod:`repro.launch.common`: the reduced config by
+default, ``--no-smoke`` for the published widths, ``--layers K`` to cut
+the depth. The persistent compile cache is on.
 """
 from __future__ import annotations
 
@@ -29,7 +33,7 @@ from repro.obs.metrics import latency_stats  # noqa: F401 — re-exported;
 
 
 def build_server(args, cfg, model, params, telemetry=None):
-    from repro.serve import ReplicaServer, pool_pages_for
+    from repro.serve import ReplicaServer
 
     injector = None
     if args.failure_model:
@@ -52,15 +56,22 @@ def build_server(args, cfg, model, params, telemetry=None):
                                  redundancy=1, mtbf=1e6, t_save=1.0,
                                  t_restart=1.0)
 
+    return ReplicaServer(model, params, n_replicas=args.replicas,
+                         injector=injector, ckpt=ckpt,
+                         engine_kwargs=engine_kwargs(args),
+                         telemetry=telemetry)
+
+
+def engine_kwargs(args) -> dict:
+    """:class:`~repro.serve.engine.ServeEngine` sizing from ``args``:
+    slots, pages, generation budget and prompt buckets."""
+    from repro.serve import pool_pages_for
     buckets = tuple(int(b) for b in args.buckets.split(","))
-    kwargs = dict(
+    return dict(
         n_slots=args.slots, page_size=args.page_size, max_new=args.max_new,
         buckets=buckets,
         n_pages=pool_pages_for(args.slots, max(buckets) + args.max_new,
                                args.page_size))
-    return ReplicaServer(model, params, n_replicas=args.replicas,
-                         injector=injector, ckpt=ckpt, engine_kwargs=kwargs,
-                         telemetry=telemetry)
 
 
 def serve_and_measure(srv, requests):
@@ -72,9 +83,10 @@ def serve_and_measure(srv, requests):
     return done, time.perf_counter() - t0
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    from repro.launch.common import add_model_args
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--arch", required=True)
+    add_model_args(ap)
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("--slots", type=int, default=4,
                     help="decode slots per replica")
@@ -100,16 +112,21 @@ def main() -> None:
                          "trace (per-replica prefill/decode/admit/evict "
                          "lanes + failure markers); metrics snapshot at "
                          "PATH.metrics.json")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv: list[str] | None = None) -> None:
+    args = build_parser().parse_args(argv)
 
     import jax
 
-    from repro.configs import smoke_config
     from repro.data import RequestStream
+    from repro.launch.common import enable_compile_cache, resolve_config
     from repro.models import build_model
     from repro.obs import Telemetry
 
-    cfg = smoke_config(args.arch)
+    enable_compile_cache()
+    cfg = resolve_config(args)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
 

@@ -1,10 +1,10 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-Runs the full SPARe+CKPT loop (Alg. 1) at a configurable scale. On this
-CPU container it runs reduced configs end-to-end (``--smoke``, default);
-on a real TPU fleet the same entry point runs the full config on the
-production mesh (``--full`` uses the sharded train step the dry-run
-lowers; per-host data feeding via the same deterministic pipeline).
+Runs the full SPARe+CKPT loop (Alg. 1) at a configurable scale. By
+default it runs the reduced same-family config (``--smoke``);
+``--no-smoke`` runs the published widths, and ``--layers K`` cuts only
+the depth (``chip_smoke.py`` at the repo root drives this launcher's
+trainer on one TPU chip that way).
 
 Failure injection comes in two flavors:
 
@@ -26,11 +26,14 @@ invariant after every recovery.
 ``--mesh`` swaps the emulated trainer for the :class:`repro.exec
 .MeshExecutor`: the identical loop (same schemes, same injectors, same
 report) but the step runs sharded over an ``n_groups x model_degree``
-device mesh with the §3.1 weighted all-reduce on the wire. On a CPU
-container the launcher forces the host platform to fan out into enough
-emulated devices automatically (the dry-run trick), so
-``python -m repro.launch.train --arch qwen2.5-3b --mesh`` works
-anywhere.
+device mesh with the §3.1 weighted all-reduce on the wire. With
+``JAX_PLATFORMS=cpu`` pinned, the launcher fans the host platform out
+into enough emulated devices (the dry-run trick), so
+``JAX_PLATFORMS=cpu python -m repro.launch.train --mesh`` works on any
+machine; otherwise the mesh is built from the real accelerator devices
+and fails if there are too few.
+
+The persistent compile cache is on (:mod:`repro.launch.common`).
 """
 from __future__ import annotations
 
@@ -93,9 +96,10 @@ def _sweep_regimes(args) -> None:
             json.dump(rows, f, indent=1)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    from repro.launch.common import add_model_args
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--arch", default="qwen2.5-3b")
+    add_model_args(ap)
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--n-groups", type=int, default=8,
                     help="SPARe data-parallel degree N")
@@ -127,8 +131,8 @@ def main() -> None:
     ap.add_argument("--mesh", action="store_true",
                     help="run on a real SPMD device mesh (repro.exec."
                          "MeshExecutor) instead of the emulated trainer; "
-                         "forces --xla_force_host_platform_device_count "
-                         "when too few devices are visible")
+                         "under JAX_PLATFORMS=cpu the host platform is "
+                         "fanned out into enough emulated devices")
     ap.add_argument("--model-degree", type=int, default=1,
                     help="tensor-parallel degree of the --mesh mesh")
     ap.add_argument("--sync", default="shard_map",
@@ -159,7 +163,6 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--report-json", default=None)
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="record telemetry and write a Perfetto-loadable "
@@ -170,48 +173,32 @@ def main() -> None:
                     help="with --trace: in-jit bucket markers + EF "
                          "residual norms (changes the compiled program; "
                          "attribution sessions only)")
-    args = ap.parse_args()
+    return ap
 
-    if args.sweep_regimes:
-        _sweep_regimes(args)
-        return
 
-    if args.mesh:
-        # must land before the FIRST jax import (jax locks the device
-        # count on init); every repro import below is function-local so
-        # this is still early enough. Append rather than setdefault —
-        # unrelated pre-set XLA_FLAGS must not silently disable the
-        # fan-out (an explicit user-set device count still wins).
-        existing = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in existing:
-            flag = ("--xla_force_host_platform_device_count="
-                    f"{args.n_groups * args.model_degree}")
-            os.environ["XLA_FLAGS"] = f"{existing} {flag}".strip()
-
-    from repro.configs import get_config, smoke_config
+def build_trainer(args, telemetry=None):
+    """The trainer ``args`` describe: a :class:`SpareTrainer`, or with
+    ``--mesh`` a :class:`~repro.exec.MeshExecutor` (elastic with
+    ``--elastic``). Logs one ``[train]`` line naming the run."""
     from repro.des import get_scheme
-    from repro.train.trainer import PoissonInjector, SpareTrainer
+    from repro.launch.common import resolve_config
+    from repro.train.trainer import SpareTrainer
 
-    cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    cfg = cfg.scaled(grad_accum=1)
+    cfg = resolve_config(args).scaled(grad_accum=1)
     r = _resolve_r(args)
     tag = "" if args.grad_compress == "none" else f"+{args.grad_compress}"
     plane = (f"{args.n_groups}x{args.model_degree}/{args.sync}{tag}"
              if args.mesh else "emulated")
     print(f"[train] arch={args.arch} N={args.n_groups} r={r} "
           f"scheme={args.scheme} steps={args.steps} mesh={plane} "
+          f"layers={cfg.n_layers} d_model={cfg.d_model} vocab={cfg.vocab} "
           f"params={cfg.param_count():,}")
-
-    tel = None
-    if args.trace is not None:
-        from repro.obs import Telemetry
-        tel = Telemetry(deep=args.trace_deep)
 
     scheme_kwargs = {} if args.scheme == "ckpt_only" else {"r": r}
     common = dict(n_groups=args.n_groups, redundancy=r, seq=args.seq,
                   per_type_batch=args.per_type_batch, seed=args.seed,
                   ckpt_dir=args.ckpt_dir, base_lr=args.lr,
-                  total_steps=args.steps, telemetry=tel,
+                  total_steps=args.steps, telemetry=telemetry,
                   scheme=get_scheme(args.scheme, **scheme_kwargs))
     if args.mesh:
         compress = None if args.grad_compress == "none" else \
@@ -225,11 +212,14 @@ def main() -> None:
         else:
             from repro.exec import MeshExecutor
             trainer = MeshExecutor(cfg, **mesh_kw)
-    elif args.elastic:
-        ap.error("--elastic needs --mesh (the elastic tier reshapes a "
-                 "real device mesh)")
     else:
         trainer = SpareTrainer(cfg, **common)
+    return trainer
+
+
+def build_injector(args):
+    """The failure injector ``args`` select, or None."""
+    from repro.train.trainer import PoissonInjector
     if args.failure_model is not None:
         from repro.train.injection import ScenarioInjector
         injector = ScenarioInjector(
@@ -240,6 +230,44 @@ def main() -> None:
         injector = PoissonInjector(args.mtbf_steps, seed=args.seed)
     else:
         injector = None
+    return injector
+
+
+def main(argv: list[str] | None = None) -> None:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+
+    if args.sweep_regimes:
+        _sweep_regimes(args)
+        return
+    if args.elastic and not args.mesh:
+        ap.error("--elastic needs --mesh (the elastic tier reshapes a "
+                 "real device mesh)")
+
+    if args.mesh and os.environ.get("JAX_PLATFORMS") == "cpu":
+        # must land before the FIRST jax import (jax locks the device
+        # count on init); every repro import below is function-local so
+        # this is still early enough. Only a run pinned to the CPU fans
+        # out: on an accelerator the mesh must be real devices, and a
+        # failed backend must not turn into an emulated CPU mesh that
+        # exits 0. Append rather than setdefault — unrelated pre-set
+        # XLA_FLAGS must not silently disable the fan-out (an explicit
+        # user-set device count still wins).
+        existing = os.environ.get("XLA_FLAGS", "")
+        if "xla_force_host_platform_device_count" not in existing:
+            flag = ("--xla_force_host_platform_device_count="
+                    f"{args.n_groups * args.model_degree}")
+            os.environ["XLA_FLAGS"] = f"{existing} {flag}".strip()
+
+    from repro.launch.common import enable_compile_cache
+    enable_compile_cache()
+
+    tel = None
+    if args.trace is not None:
+        from repro.obs import Telemetry
+        tel = Telemetry(deep=args.trace_deep)
+    trainer = build_trainer(args, telemetry=tel)
+    injector = build_injector(args)
     t0 = time.perf_counter()
     rep = trainer.run(args.steps, injector=injector,
                       verify_equivalence=args.verify_equivalence)

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.collectives import shard_map_compat
 
 from .config import ModelConfig, MoEConfig
 
@@ -189,11 +188,11 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
         in_specs.append({"w_gate": P(None, ep_axis), "w_up": P(None, ep_axis),
                          "w_down": P(ep_axis, None)})
         args.append(shared)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             lambda a, b, c, dsh: body(a, b, c, dsh), mesh=mesh,
-            in_specs=tuple(in_specs), out_specs=x_spec)
+            in_specs=tuple(in_specs), out_specs=x_spec, check_vma=False)
     else:
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             lambda a, b, c: body(a, b, c, None), mesh=mesh,
-            in_specs=tuple(in_specs), out_specs=x_spec)
+            in_specs=tuple(in_specs), out_specs=x_spec, check_vma=False)
     return fn(*args)

@@ -678,39 +678,49 @@ class SpareTrainer:
                 # again — the step below re-collects every type
             if wiped:
                 continue
-            with maybe_span(
-                    tel, "step",
-                    args=(None if tel is None else
-                          {"step": self.step,
-                           "s_a": self.state.s_a})) as step_span:
-                with maybe_span(tel, "compute"):
-                    new_params, new_opt, metrics = self._dispatch(report)
-                    self.params, self.opt_state = new_params, new_opt
-                    loss = float(metrics["loss"])   # blocks on the device
-                report.losses.append(loss)
-                self.step += 1
-                report.steps_done += 1
-                if self.step % snapshot_every == 0:
-                    with maybe_span(tel, "ckpt_save"):
-                        self._snapshot_now()
-                        if self.ckpt is not None:
-                            self.ckpt.maybe_save(
-                                self.step, (self.params, self.opt_state))
-                            report.ckpt_saves = self.ckpt.saves
-            if tel is not None:
-                tel.counter("train.steps").inc()
-                tel.histogram("train.step_seconds").observe(step_span.dur)
-                if step_span.dur > 0:
-                    tel.gauge("train.steps_per_s").set(1.0 / step_span.dur)
-            # gray-failure tier: one detector observation per completed
-            # step; may demote stragglers or re-admit healed groups
-            self._health_tick(injector, report)
+            self.train_step(report, injector, snapshot_every)
         if self.ckpt is not None:
             self.ckpt.wait()
             # forced/trailing saves land between snapshot boundaries:
             # refresh after the final wait so the report counts them all
             report.ckpt_saves = self.ckpt.saves
         return report
+
+    def train_step(self, report: TrainReport, injector=None,
+                   snapshot_every: int = 10) -> float:
+        """One step of the Alg. 1 loop on the current schedule, as
+        :meth:`run` takes it once failures are handled: the compiled
+        step, the periodic snapshot, telemetry and the health tick.
+        Returns the loss (read back, so the step's forward has run)."""
+        tel = self.telemetry
+        with maybe_span(
+                tel, "step",
+                args=(None if tel is None else
+                      {"step": self.step,
+                       "s_a": self.state.s_a})) as step_span:
+            with maybe_span(tel, "compute"):
+                new_params, new_opt, metrics = self._dispatch(report)
+                self.params, self.opt_state = new_params, new_opt
+                loss = float(metrics["loss"])   # blocks on the device
+            report.losses.append(loss)
+            self.step += 1
+            report.steps_done += 1
+            if self.step % snapshot_every == 0:
+                with maybe_span(tel, "ckpt_save"):
+                    self._snapshot_now()
+                    if self.ckpt is not None:
+                        self.ckpt.maybe_save(
+                            self.step, (self.params, self.opt_state))
+                        report.ckpt_saves = self.ckpt.saves
+        if tel is not None:
+            tel.counter("train.steps").inc()
+            tel.histogram("train.step_seconds").observe(step_span.dur)
+            if step_span.dur > 0:
+                tel.gauge("train.steps_per_s").set(1.0 / step_span.dur)
+        # gray-failure tier: one detector observation per completed
+        # step; may demote stragglers or re-admit healed groups
+        self._health_tick(injector, report)
+        return loss
 
     # ---------------------------------------------------------------- #
     def _batch_grads(self, batch: dict):

@@ -45,10 +45,6 @@ def test_emulated_mesh_too_large_names_the_fix():
 def test_production_mesh_geometry_subprocess():
     """Real ``make_production_mesh`` construction at 512 forced host
     devices: shapes, axis names, and DP degrees of both launch targets.
-
-    Also guards the jax-version compat shim — ``axis_types`` /
-    ``jax.sharding.AxisType`` only exist on newer jax, and the builder
-    must work either way.
     """
     prog = textwrap.dedent("""
         import os

@@ -1,0 +1,130 @@
+"""Main-path programs compiled for a TPU v5e chip, without a chip.
+
+The TPU compiler ships with the installed jax, and compiles for a chip
+that is described rather than attached. These tests hand it shapes on
+described v5e devices: what Mosaic or XLA would refuse on the chip (a
+block shape off the tiling, a program that does not fit 16 GiB of HBM)
+fails here, at no chip time. Nothing runs, so they say nothing about
+results or speed.
+
+Only one process at a time may load the TPU library, and it keeps it
+until it exits. So the topology is described inside a module-scoped
+fixture, never while a module is imported, and every compile test lives
+in this one file: under pytest-xdist only the worker given this file
+loads the library, and every worker collects the same tests.
+"""
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+V5E_HBM_BYTES = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")     # no compiler logs in /tmp
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure: no compiler
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _peak_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("n", [8 << 20, 1_000_003],
+                         ids=["bucket-8Mi", "odd-padded"])
+def test_int8_ef_kernel_compiles_for_v5e(one_chip, n):
+    """The int8-EF kernel at the mesh executor's real bucket size
+    (``max_bucket_elems`` = 8 Mi fp32) and at an odd size that needs
+    padding, compiled by Mosaic (not interpreted)."""
+    from repro.kernels.int8_ef import int8_ef_pallas
+
+    g = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(int8_ef_pallas).lower(g, g).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen_train_step_fits_one_v5e(one_chip):
+    """The single-chip SPARe train step of qwen2.5-3b at every published
+    width (1 layer; N=4 groups of one 1024-token sequence) compiles for
+    v5e and fits its HBM."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.optim import adamw_init
+    from repro.train.step import make_train_step
+
+    cfg = get_config("qwen2.5-3b").scaled(n_layers=1, grad_accum=1)
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(adamw_init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 4, 1024), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((1, 4, 1024), jnp.int32),
+             "weights": jax.ShapeDtypeStruct((1, 4), jnp.float32)}
+    step = jax.jit(make_train_step(model, total_steps=100),
+                   donate_argnums=(0, 1))
+    compiled = step.lower(on_chip(params), on_chip(opt),
+                          on_chip(batch)).compile()
+    assert 0 < _peak_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_int8_ef_sync_compiles_over_v5e_mesh(topo, monkeypatch):
+    """The compressed bucket sync over a 4-chip v5e mesh with the Pallas
+    kernel in it. Its int8 all-to-all and all-gather must move rows of
+    ``CompressedBucketSync.LANES``: on flat int8 chunks the TPU compile
+    time grows with the bucket size."""
+    import re
+
+    import numpy as np
+
+    import repro.kernels.ops as ops
+    from repro.dist.collectives import CompressedBucketSync, bucket_layout
+
+    # code that asks jax.default_backend() sees the CPU here: steer the
+    # kernel choice to the chip's, and compile it for real (not interpret)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices).reshape(4, 1),
+                             ("data", "model"))
+    n = 8 << 20
+    layout = bucket_layout({"w": jax.ShapeDtypeStruct((n,), jnp.float32)},
+                           max_bucket_elems=n,
+                           pad_to=CompressedBucketSync.LANES * 4)
+    sync = CompressedBucketSync(layout, 4, "data")
+    specs = sync.state_specs()
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        sync.init_state(), specs)
+    grads = {"w": jax.ShapeDtypeStruct((n,), jnp.float32,
+                                       sharding=NamedSharding(mesh, P()))}
+    fn = jax.shard_map(sync, mesh=mesh, in_specs=(P(), specs),
+                       out_specs=(P(), specs), check_vma=False)
+    text = jax.jit(fn).lower(grads, state).compile().as_text()
+    assert "tpu_custom_call" in text
+    # int8 collectives in the compiled module, e.g.
+    # %all_to_all.3 = s8[4,16384,128]{...} all-to-all(...)
+    int8_colls = re.findall(
+        r"= s8\[([\d,]+)\]\S* (all-to-all|all-gather)(?:-start)?\(", text)
+    assert {op for _, op in int8_colls} == {"all-to-all", "all-gather"}
+    for dims, op in int8_colls:
+        assert int(dims.split(",")[-1]) == CompressedBucketSync.LANES, \
+            f"int8 {op} of s8[{dims}] is not lane-aligned"
