@@ -97,7 +97,8 @@ def all_reduce_grads(grads, axis_name: str):
     a failure never changes the collective schedule (paper §3.1, "zero
     extra collectives").
     """
-    return jax.tree.map(lambda g: psum_partial(g, axis_name), grads)
+    with jax.named_scope("sync"):
+        return jax.tree.map(lambda g: psum_partial(g, axis_name), grads)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -290,6 +291,7 @@ class BucketedAllReduce:
         self.layout = layout
         self.axis_name = axis_name
 
+    @jax.named_scope("sync")
     def __call__(self, grads):
         bufs = flatten_grads(self.layout, grads)
         bufs = [psum_partial(b, self.axis_name) for b in bufs]
@@ -397,6 +399,7 @@ class CompressedBucketSync:
         out = (full_q.astype(jnp.float32) * full_s[:, None]).reshape(-1)
         return out, e1_new, e2_new
 
+    @jax.named_scope("sync")
     def __call__(self, grads, state):
         """Local (per-device) view: ``state['err1'][b]`` is this
         device's full-bucket residual, ``state['err2'][b]`` its owned

@@ -402,12 +402,14 @@ class MeshExecutor(SpareTrainer):
     def _dispatch(self, report: TrainReport):
         batch = self._device_batch()
         fn = self._compiled(self.state.s_a, report)
-        if self.grad_compress:
-            out = fn(self.params, self.opt_state, batch, self._ef_state)
-            params, opt_state, metrics, self._ef_state = out
-            result = (params, opt_state, metrics)
-        else:
-            result = fn(self.params, self.opt_state, batch)
+        with maybe_span(self.telemetry, "dispatch"):
+            if self.grad_compress:
+                out = fn(self.params, self.opt_state, batch,
+                         self._ef_state)
+                params, opt_state, metrics, self._ef_state = out
+                result = (params, opt_state, metrics)
+            else:
+                result = fn(self.params, self.opt_state, batch)
         # the step is dispatched (async); overlap the next batch build
         self._prefetch_next()
         if self.telemetry is not None:

@@ -109,7 +109,6 @@ def certify_executors() -> Report:
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
     import jax
-    import jax.numpy as jnp
 
     from repro.analysis import (donation_audit, hot_path_purity,
                                 schedule_determinism_executor,
@@ -211,15 +210,11 @@ def certify_executors() -> Report:
     dex.close()
 
     # the emulation trainer's jit site (donate_argnums=(0, 1))
-    from repro.data.pipeline import spare_batch
-    from repro.train.trainer import SpareTrainer, TrainReport
+    from repro.train.trainer import SpareTrainer
 
     tr = SpareTrainer(cfg, n_groups=4, redundancy=2, seq=32,
                       per_type_batch=2, total_steps=50)
-    batch = {k: jnp.asarray(v) for k, v in
-             spare_batch(tr.pipeline, tr.state, 0).items()}
-    fn = tr._compiled(tr.state.s_a, TrainReport())
-    text = fn.lower(tr.params, tr.opt_state, batch).compile().as_text()
+    text = tr.compiled_step_text()
     donated = leaves(tr.params) + leaves(tr.opt_state)
     report.extend(donation_audit(text, donated, "trainer:spare"))
     report.extend(hot_path_purity(text, "trainer:spare"))

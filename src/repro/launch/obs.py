@@ -14,6 +14,11 @@ Renders a dumped telemetry trace (:meth:`repro.obs.Telemetry.dump_trace`
   accounted on its clock);
 * optionally a **text timeline** of the main track (``--timeline``).
 
+The trainer's phases on the main track: ``step`` holds ``batch`` (the
+mesh executor's ``feed``), ``dispatch``, ``loss_read`` and, on snapshot
+steps, ``ckpt_save``; ``recover`` spans lie between steps. The span
+vocabulary is :mod:`repro.obs.trace`'s.
+
 Exit status enforces the CI gates: ``--assert-coverage 0.95`` and
 ``--assert-recovery-markers`` (at least one failure marker AND one
 recover span — an injected-failure run whose trace shows neither is a

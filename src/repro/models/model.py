@@ -12,6 +12,11 @@ depth while representing every assigned family:
 
 Decode threads per-layer caches through the same scans (cache stacks are
 the scanned xs/ys; the hidden state is the carry).
+
+Each layer runs under a ``jax.named_scope`` of
+:data:`repro.obs.DEVICE_SCOPES` (``embed``, ``attention``/``ssm``,
+``mlp``/``moe``, ``head``), which names its device operations in a
+profiler trace at no run-time cost.
 """
 from __future__ import annotations
 
@@ -28,6 +33,9 @@ from .config import ModelConfig
 from .layers import ACT_DTYPE, cross_entropy, embed_lookup, init_linear, rmsnorm
 
 __all__ = ["Model", "build_model", "segments_of"]
+
+# block kind's mixer -> its device scope (repro.obs.DEVICE_SCOPES)
+_MIXER_SCOPE = {"attn": "attention", "mamba": "ssm"}
 
 
 def segments_of(cfg: ModelConfig) -> list[tuple[tuple[str, ...], int]]:
@@ -270,46 +278,53 @@ class Model:
         _, _, mlp = kind.partition("_")
         if not mlp:
             return x
-        h = rmsnorm(x, p["ln2"], self.cfg.norm_eps)
-        if mlp == "dense":
-            from .layers import mlp2, swiglu
+        if mlp != "dense":
+            with jax.named_scope("moe"):
+                h = rmsnorm(x, p["ln2"], self.cfg.norm_eps)
+                return x + moe_mod.moe_ffn(
+                    h, p["moe"], self.cfg, mesh=self.mesh,
+                    dp_axes=self.dp_axes, ep_axis=self.ep_axis)
+        from .layers import mlp2, swiglu
+        with jax.named_scope("mlp"):
+            h = rmsnorm(x, p["ln2"], self.cfg.norm_eps)
             if self.cfg.mlp_kind != "swiglu":
                 return x + mlp2(h, p["mlp"]["w_in"], p["mlp"]["w_out"],
                                 kind=self.cfg.mlp_kind)
             return x + swiglu(h, p["mlp"]["w_gate"], p["mlp"]["w_up"],
                               p["mlp"]["w_down"])
-        return x + moe_mod.moe_ffn(h, p["moe"], self.cfg, mesh=self.mesh,
-                                   dp_axes=self.dp_axes, ep_axis=self.ep_axis)
 
     def _block_forward(self, x, p, kind, positions):
         cfg = self.cfg
         mixer = kind.partition("_")[0]
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        if mixer == "attn":
-            if cfg.attn_kind == "mla":
-                x = x + attn.mla_forward(h, p["attn"], cfg, positions,
-                                         chunk=self.attn_chunk)
+        with jax.named_scope(_MIXER_SCOPE[mixer]):
+            h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+            if mixer == "attn":
+                if cfg.attn_kind == "mla":
+                    x = x + attn.mla_forward(h, p["attn"], cfg, positions,
+                                             chunk=self.attn_chunk)
+                else:
+                    hc = None
+                    if self.mesh is not None:
+                        hc = lambda t: self._constrain(t, None, "model",
+                                                       None)
+                    x = x + attn.gqa_forward(h, p["attn"], cfg, positions,
+                                             chunk=self.attn_chunk,
+                                             head_constrain=hc)
             else:
-                hc = None
-                if self.mesh is not None:
-                    hc = lambda t: self._constrain(t, None, "model", None)
-                x = x + attn.gqa_forward(h, p["attn"], cfg, positions,
-                                         chunk=self.attn_chunk,
-                                         head_constrain=hc)
-        else:
-            x = x + ssm_mod.mamba_forward(h, p["mamba"], cfg)
+                x = x + ssm_mod.mamba_forward(h, p["mamba"], cfg)
         return self._mlp_part(x, p, kind)
 
     def _block_decode(self, x, p, kind, cache, pos):
         cfg = self.cfg
         mixer = kind.partition("_")[0]
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        if mixer == "attn":
-            dec = attn.mla_decode if cfg.attn_kind == "mla" else attn.gqa_decode
-            y, cache = dec(h, p["attn"], cfg, cache, pos)
-            x = x + y
-        else:
-            y, cache = ssm_mod.mamba_decode(h, p["mamba"], cfg, cache)
+        with jax.named_scope(_MIXER_SCOPE[mixer]):
+            h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+            if mixer == "attn":
+                dec = (attn.mla_decode if cfg.attn_kind == "mla"
+                       else attn.gqa_decode)
+                y, cache = dec(h, p["attn"], cfg, cache, pos)
+            else:
+                y, cache = ssm_mod.mamba_decode(h, p["mamba"], cfg, cache)
             x = x + y
         return self._mlp_part(x, p, kind), cache
 
@@ -317,49 +332,70 @@ class Model:
         """Forward one block AND capture its decode cache (fused prefill)."""
         cfg = self.cfg
         mixer = kind.partition("_")[0]
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        if mixer == "attn":
-            if cfg.attn_kind == "mla":
-                y, cache = attn.mla_forward(h, p["attn"], cfg, positions,
-                                            chunk=self.attn_chunk,
-                                            return_kv=True)
+        with jax.named_scope(_MIXER_SCOPE[mixer]):
+            h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+            if mixer == "attn":
+                if cfg.attn_kind == "mla":
+                    y, cache = attn.mla_forward(h, p["attn"], cfg, positions,
+                                                chunk=self.attn_chunk,
+                                                return_kv=True)
+                else:
+                    hc = None
+                    if self.mesh is not None:
+                        hc = lambda t: self._constrain(t, None, "model",
+                                                       None)
+                    y, cache = attn.gqa_forward(h, p["attn"], cfg, positions,
+                                                chunk=self.attn_chunk,
+                                                head_constrain=hc,
+                                                return_kv=True)
             else:
-                hc = None
-                if self.mesh is not None:
-                    hc = lambda t: self._constrain(t, None, "model", None)
-                y, cache = attn.gqa_forward(h, p["attn"], cfg, positions,
-                                            chunk=self.attn_chunk,
-                                            head_constrain=hc, return_kv=True)
-        else:
-            y, cache = ssm_mod.mamba_forward(h, p["mamba"], cfg,
-                                             return_cache=True)
-        return self._mlp_part(x + y, p, kind), cache
+                y, cache = ssm_mod.mamba_forward(h, p["mamba"], cfg,
+                                                 return_cache=True)
+            x = x + y
+        return self._mlp_part(x, p, kind), cache
 
     def _block_decode_paged(self, x, p, kind, cache, table, pos):
         cfg = self.cfg
         mixer = kind.partition("_")[0]
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        if mixer == "attn":
-            dec = (attn.mla_decode_paged if cfg.attn_kind == "mla"
-                   else attn.gqa_decode_paged)
-            y, cache = dec(h, p["attn"], cfg, cache, table, pos)
-        else:
-            # SSD state is O(1) per sequence — the slot IS the page; the
-            # dense decode path already advances every row independently
-            y, cache = ssm_mod.mamba_decode(h, p["mamba"], cfg, cache)
-        return self._mlp_part(x + y, p, kind), cache
+        with jax.named_scope(_MIXER_SCOPE[mixer]):
+            h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+            if mixer == "attn":
+                dec = (attn.mla_decode_paged if cfg.attn_kind == "mla"
+                       else attn.gqa_decode_paged)
+                y, cache = dec(h, p["attn"], cfg, cache, table, pos)
+            else:
+                # SSD state is O(1) per sequence — the slot IS the page;
+                # the dense decode path already advances every row
+                # independently
+                y, cache = ssm_mod.mamba_decode(h, p["mamba"], cfg, cache)
+            x = x + y
+        return self._mlp_part(x, p, kind), cache
+
+    # ---------------- embedding / head ---------------- #
+    def _embed(self, params, tokens, embeds) -> jax.Array:
+        with jax.named_scope("embed"):
+            if embeds is not None:
+                x = embeds.astype(ACT_DTYPE)
+            else:
+                assert tokens is not None
+                x = embed_lookup(params["embed"], tokens)
+            return self._constrain(x)
+
+    def _head(self, params, x) -> jax.Array:
+        """Final norm, the tied or untied head and pad masking."""
+        cfg = self.cfg
+        with jax.named_scope("head"):
+            x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            return self._mask_pad(jnp.dot(x, head))
 
     # ---------------- forward / loss ---------------- #
     def forward(self, params: dict, tokens: jax.Array | None = None,
                 embeds: jax.Array | None = None) -> jax.Array:
         """Training forward. Returns logits (B, S, V)."""
         cfg = self.cfg
-        if embeds is not None:
-            x = embeds.astype(ACT_DTYPE)
-        else:
-            assert tokens is not None
-            x = embed_lookup(params["embed"], tokens)
-        x = self._constrain(x)
+        x = self._embed(params, tokens, embeds)
         b, s = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
@@ -379,10 +415,7 @@ class Model:
                 body = jax.checkpoint(body, policy=policy)
             x, _ = jax.lax.scan(body, x, seg)
 
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = self._mask_pad(jnp.dot(x, head))
+        logits = self._head(params, x)
         return self._constrain(logits, None, "model")
 
     def loss(self, params: dict, batch: dict) -> jax.Array:
@@ -406,12 +439,7 @@ class Model:
         bucket for this reason).
         """
         cfg = self.cfg
-        if embeds is not None:
-            x = embeds.astype(ACT_DTYPE)
-        else:
-            assert tokens is not None
-            x = embed_lookup(params["embed"], tokens)
-        x = self._constrain(x)
+        x = self._embed(params, tokens, embeds)
         b, s = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
@@ -428,10 +456,7 @@ class Model:
             x, seg_cache = jax.lax.scan(body, x, seg)
             states.append(seg_cache)
 
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = self._mask_pad(jnp.dot(x, head))
+        logits = self._head(params, x)
         return self._constrain(logits, None, "model"), states
 
     # ---------------- decode ---------------- #
@@ -461,12 +486,7 @@ class Model:
         """One-token step. tokens (B, 1) or embeds (B, 1, D); pos () int32.
         Returns (logits (B, 1, V), new state)."""
         cfg = self.cfg
-        if embeds is not None:
-            x = embeds.astype(ACT_DTYPE)
-        else:
-            assert tokens is not None
-            x = embed_lookup(params["embed"], tokens)
-        x = self._constrain(x)
+        x = self._embed(params, tokens, embeds)
 
         new_states = []
         for (pattern, n_rep), seg, seg_cache in zip(
@@ -481,9 +501,7 @@ class Model:
             x, new_cache = jax.lax.scan(body, x, (seg, seg_cache))
             new_states.append(new_cache)
 
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-        return self._mask_pad(jnp.dot(x, head)), new_states
+        return self._head(params, x), new_states
 
     # ---------------- paged decode ---------------- #
     def init_paged_state(self, n_slots: int, n_pages: int,
@@ -528,12 +546,7 @@ class Model:
         is what keeps continuous batching recompile-free.
         """
         cfg = self.cfg
-        if embeds is not None:
-            x = embeds.astype(ACT_DTYPE)
-        else:
-            assert tokens is not None
-            x = embed_lookup(params["embed"], tokens)
-        x = self._constrain(x)
+        x = self._embed(params, tokens, embeds)
 
         new_states = []
         for (pattern, n_rep), seg, seg_cache in zip(
@@ -549,9 +562,7 @@ class Model:
             x, new_cache = jax.lax.scan(body, x, (seg, seg_cache))
             new_states.append(new_cache)
 
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-        return self._mask_pad(jnp.dot(x, head)), new_states
+        return self._head(params, x), new_states
 
 
 def build_model(cfg: ModelConfig, mesh=None, dp_axes=("data",),

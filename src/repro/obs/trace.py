@@ -16,6 +16,12 @@ The tracing half of the observability substrate. Design constraints:
   format (``{"traceEvents": [...]}``, complete ``"X"`` events + instant
   ``"i"`` markers + ``"M"`` thread-name metadata). Load it at
   https://ui.perfetto.dev or ``chrome://tracing`` unchanged.
+* **on the profiler's clock** — every span (recorded, or the
+  metrics-only one of ``Telemetry(trace=False)``) also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name: about a
+  microsecond while no profiler runs, and under
+  ``jax.profiler.trace`` the span lands on the ``/host:`` plane of the
+  ``.xplane.pb``, on the same clock as the device's operations.
 
 Tracks are named lanes (``main``, ``dp/<g>``, ``replica/<r>``): each
 becomes one Perfetto thread row, created on first use. Failure and
@@ -28,8 +34,10 @@ attribution table keys off these names):
 
 ====================  ==================================================
 ``step``              one trainer loop iteration (main track)
-``compute``           device step: dispatch through blocking on loss
-``feed``              per-host input feed wait (mesh executor)
+``batch``             host batch build + host-to-device copy (trainer)
+``feed``              per-host input feed wait (mesh executor's batch)
+``dispatch``          the jitted step call, until it returns (async)
+``loss_read``         the blocking ``float(loss)`` read-back
 ``grad_sync``         deep-mode marker scope for the compressed sync
 ``bucket/<i>``        deep-mode per-bucket markers inside the jitted sync
 ``ckpt_save``         snapshot + async checkpoint save
@@ -52,6 +60,13 @@ __all__ = ["TraceRecorder", "Telemetry", "maybe_span", "tick",
            "load_trace", "TraceView", "Span", "Instant"]
 
 
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, with jax imported lazily
+    so that this module imports without it."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
+
+
 def tick(step: float = 1.0):
     """A deterministic monotone clock for byte-stable traces/tests."""
     state = {"t": 0.0}
@@ -68,9 +83,10 @@ class _SpanCtx:
 
     Exposes ``dur`` (seconds) after exit so callers can feed the same
     measurement into a histogram without a second clock read pair.
+    Also opens a profiler annotation of the same name.
     """
 
-    __slots__ = ("_rec", "name", "track", "args", "t0", "dur")
+    __slots__ = ("_rec", "name", "track", "args", "t0", "dur", "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, track: str, args):
         self._rec = rec
@@ -79,13 +95,16 @@ class _SpanCtx:
         self.args = args
         self.t0 = 0.0
         self.dur = 0.0
+        self._ann = _annotation(name)
 
     def __enter__(self) -> "_SpanCtx":
+        self._ann.__enter__()
         self.t0 = self._rec._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = self._rec._clock()
+        self._ann.__exit__(*exc)
         self.dur = t1 - self.t0
         self._rec._events.append(
             ("X", self.name, self.track, self.t0, t1, self.args))
@@ -113,21 +132,26 @@ class _TimedSpan:
     What ``Telemetry(trace=False).span(...)`` hands out, so callers
     that feed a span's duration into a histogram (the trainer's
     ``train.step_seconds``) work identically with span recording off.
+    The profiler annotation is still opened: a running profiler sees
+    the span either way.
     """
 
-    __slots__ = ("_clock", "t0", "dur")
+    __slots__ = ("_clock", "t0", "dur", "_ann")
 
-    def __init__(self, clock):
+    def __init__(self, clock, name: str):
         self._clock = clock
         self.t0 = 0.0
         self.dur = 0.0
+        self._ann = _annotation(name)
 
     def __enter__(self) -> "_TimedSpan":
+        self._ann.__enter__()
         self.t0 = self._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.dur = self._clock() - self.t0
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -227,8 +251,10 @@ class Telemetry:
 
     # -- tracing --------------------------------------------------- #
     def span(self, name: str, track: str = "main", args: dict | None = None):
+        """A span named ``name``, also on the profiler's host plane."""
         if self.tracer is None:
-            return _TimedSpan(self._clock)     # metrics-only: dur still real
+            # metrics-only: dur still real
+            return _TimedSpan(self._clock, name)
         return self.tracer.span(name, track, args)
 
     def instant(self, name: str, track: str = "main",

@@ -180,9 +180,11 @@ class ServeEngine:
             args = (self.params, self.pools,
                     jnp.asarray(self.table), jnp.asarray(self.pos),
                     jnp.asarray(self.next_tok[:, None]))
-            return jax.jit(
-                lambda p, s, t, pos, tok: fn(p, s, t, pos, tokens=tok),
-                donate_argnums=(1,)).lower(*args).compile()
+
+            def serve_decode(p, s, t, pos, tok):
+                return fn(p, s, t, pos, tokens=tok)
+            return jax.jit(serve_decode, donate_argnums=(1,)).lower(
+                *args).compile()
         return self.cache.get(
             ("decode",), build,
             donated_leaves=len(jax.tree_util.tree_leaves(self.pools)))
@@ -195,9 +197,10 @@ class ServeEngine:
         def build():
             fn = make_prefill(self.model, return_cache=True)
             toks = jnp.zeros((1, length), jnp.int32)
-            return jax.jit(
-                lambda p, t: fn(p, tokens=t)).lower(
-                    self.params, toks).compile()
+
+            def serve_prefill(p, t):
+                return fn(p, tokens=t)
+            return jax.jit(serve_prefill).lower(self.params, toks).compile()
         return self.cache.get(("prefill", length), build)
 
     def _write_exe(self, length: int):

@@ -108,7 +108,7 @@ def make_cache_writer(model: Model):
     Jit per prompt-length bucket (L and n_alloc are shape-static).
     """
 
-    def write(paged, dense, pages, slot):
+    def serve_cache_write(paged, dense, pages, slot):
         new_state = []
         for seg_pool, seg_dense in zip(paged, dense):
             per_pos = []
@@ -135,4 +135,4 @@ def make_cache_writer(model: Model):
             new_state.append(tuple(per_pos))
         return new_state
 
-    return write
+    return serve_cache_write

@@ -16,6 +16,11 @@ The stack axis is scanned (gradient accumulation): activation memory is
 one microbatch deep regardless of S_A, and a recompile happens only when
 S_A itself changes (S_A in {1..4} in practice; each depth is compiled
 once and cached).
+
+Each part of the step runs under one ``jax.named_scope`` of
+:data:`repro.obs.DEVICE_SCOPES` (op metadata only, no run-time cost):
+the model's layers name themselves, the loss's fp32 cross-entropy is
+``head``, the accumulator ``grad_accum``, the update ``optimizer``.
 """
 from __future__ import annotations
 
@@ -49,12 +54,14 @@ def weighted_loss(model: Model, params: Any, micro: dict,
     """
     logits = model.forward(params, tokens=micro.get("tokens"),
                            embeds=micro.get("embeds"))
-    logits = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, micro["labels"][..., None],
-                                 axis=-1)[..., 0]
-    ce = jnp.mean(lse - picked, axis=-1)           # (b,) per-example mean
-    return weighted_all_reduce(ce, micro["weights"], axis_name=axis_name)
+    with jax.named_scope("head"):
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, micro["labels"][..., None],
+                                     axis=-1)[..., 0]
+        ce = jnp.mean(lse - picked, axis=-1)       # (b,) per-example mean
+        return weighted_all_reduce(ce, micro["weights"],
+                                   axis_name=axis_name)
 
 
 def make_train_step(model: Model, *, base_lr: float = 3e-4,
@@ -97,11 +104,12 @@ def make_train_step(model: Model, *, base_lr: float = 3e-4,
 
     def accumulate(params, batch):
         # batch leaves: (n_micro, b, ...) — scan-accumulate gradients
-        zero = jax.tree.map(
-            lambda p: jnp.zeros(p.shape, acc_dtype), params)
-        if grad_shardings is not None:
-            zero = jax.tree.map(jax.lax.with_sharding_constraint, zero,
-                                grad_shardings)
+        with jax.named_scope("grad_accum"):
+            zero = jax.tree.map(
+                lambda p: jnp.zeros(p.shape, acc_dtype), params)
+            if grad_shardings is not None:
+                zero = jax.tree.map(jax.lax.with_sharding_constraint, zero,
+                                    grad_shardings)
 
         def acc(carry, micro):
             loss_acc, g_acc = carry
@@ -112,14 +120,16 @@ def make_train_step(model: Model, *, base_lr: float = 3e-4,
                 # gradient to replicated form before the (sharded) add
                 g = jax.tree.map(jax.lax.with_sharding_constraint, g,
                                  grad_shardings)
-            g_acc = jax.tree.map(
-                lambda a, b: a + b.astype(acc_dtype), g_acc, g)
+            with jax.named_scope("grad_accum"):
+                g_acc = jax.tree.map(
+                    lambda a, b: a + b.astype(acc_dtype), g_acc, g)
             return (loss_acc + loss, g_acc), None
 
         (loss, grads), _ = jax.lax.scan(acc, (jnp.zeros((), jnp.float32), zero),
                                         batch)
         return loss, grads
 
+    @jax.named_scope("optimizer")
     def update(params, opt_state, loss, grads):
         # step+1: opt.step counts *completed* updates; lr(0)=0 would make
         # the first update a silent no-op

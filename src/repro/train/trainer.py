@@ -215,19 +215,38 @@ class SpareTrainer:
         self._schedule_version = 0
 
     # ---------------------------------------------------------------- #
-    def _compiled(self, s_a: int, report: TrainReport):
+    def _compiled(self, s_a: int, report: TrainReport | None = None):
         if s_a not in self._jitted:
             self._jitted[s_a] = jax.jit(self._step_fn, donate_argnums=(0, 1))
-            report.recompiles += 1
+            if report is not None:
+                report.recompiles += 1
             if self.telemetry is not None:
                 self.telemetry.counter("train.recompiles").inc()
         return self._jitted[s_a]
 
+    def _step_batch(self, state, step: int) -> dict:
+        """Step ``step``'s batch under ``state``'s schedule, on device."""
+        batch_np = spare_batch(self.pipeline, state, step)
+        return {k: jnp.asarray(v) for k, v in batch_np.items()}
+
     def _dispatch(self, report: TrainReport):
-        batch_np = spare_batch(self.pipeline, self.state, self.step)
-        batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+        tel = self.telemetry
+        with maybe_span(tel, "batch"):
+            batch = self._step_batch(self.state, self.step)
         fn = self._compiled(self.state.s_a, report)
-        return fn(self.params, self.opt_state, batch)
+        with maybe_span(tel, "dispatch"):
+            return fn(self.params, self.opt_state, batch)
+
+    def compiled_step_text(self, state=None) -> str:
+        """Post-optimisation HLO of the step for the given (default:
+        current) schedule, through the per-``S_A`` cache: the program
+        the trainer runs, whose instruction names a profiler trace's
+        device operations carry."""
+        state = self.state if state is None else state
+        batch = self._step_batch(state, self.step)
+        fn = self._compiled(state.s_a)
+        return fn.lower(self.params, self.opt_state,
+                        batch).compile().as_text()
 
     # ---------------------------------------------------------------- #
     # snapshot tiers                                                   #
@@ -698,9 +717,9 @@ class SpareTrainer:
                 args=(None if tel is None else
                       {"step": self.step,
                        "s_a": self.state.s_a})) as step_span:
-            with maybe_span(tel, "compute"):
-                new_params, new_opt, metrics = self._dispatch(report)
-                self.params, self.opt_state = new_params, new_opt
+            new_params, new_opt, metrics = self._dispatch(report)
+            self.params, self.opt_state = new_params, new_opt
+            with maybe_span(tel, "loss_read"):
                 loss = float(metrics["loss"])   # blocks on the device
             report.losses.append(loss)
             self.step += 1
@@ -715,8 +734,6 @@ class SpareTrainer:
         if tel is not None:
             tel.counter("train.steps").inc()
             tel.histogram("train.step_seconds").observe(step_span.dur)
-            if step_span.dur > 0:
-                tel.gauge("train.steps_per_s").set(1.0 / step_span.dur)
         # gray-failure tier: one detector observation per completed
         # step; may demote stragglers or re-admit healed groups
         self._health_tick(injector, report)
@@ -746,9 +763,7 @@ class SpareTrainer:
         weight 1/N each) — the §3.1 equivalence oracle used by tests."""
         step = self.step if step is None else step
         pristine = SpareState(self.state.n, self.state.r)
-        batch_np = spare_batch(self.pipeline, pristine, step)
-        batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-        return self._batch_grads(batch)
+        return self._batch_grads(self._step_batch(pristine, step))
 
     def equivalence_error(self, step: int | None = None) -> float:
         """§3.1 check: relative gradient-equivalence error of the current
@@ -764,6 +779,4 @@ class SpareTrainer:
         """Gradient under the *current* (possibly failed/reordered)
         schedule — must equal :meth:`vanilla_reference_grads` exactly."""
         step = self.step if step is None else step
-        batch_np = spare_batch(self.pipeline, self.state, step)
-        batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-        return self._batch_grads(batch)
+        return self._batch_grads(self._step_batch(self.state, step))

@@ -283,7 +283,7 @@ def test_obs_cli_attribution_on_real_run(cfg, tmp_path, capsys):
     kinds = {r["kind"] for r in ana["recovery_events"]}
     assert kinds <= {"mask", "restart"}
     phases = {p["phase"] for p in ana["phases"]}
-    assert {"step", "compute"} <= phases
+    assert {"step", "batch", "dispatch", "loss_read"} <= phases
 
     rc = obs_cli.main([str(path), "--assert-coverage", "0.95",
                        "--assert-recovery-markers",

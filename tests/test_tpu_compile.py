@@ -59,10 +59,11 @@ def test_int8_ef_kernel_compiles_for_v5e(one_chip, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_qwen_train_step_fits_one_v5e(one_chip):
+@pytest.fixture(scope="module")
+def qwen_step(one_chip):
     """The single-chip SPARe train step of qwen2.5-3b at every published
-    width (1 layer; N=4 groups of one 1024-token sequence) compiles for
-    v5e and fits its HBM."""
+    width (1 layer; N=4 groups of one 1024-token sequence), compiled for
+    v5e."""
     from repro.configs import get_config
     from repro.models import build_model
     from repro.optim import adamw_init
@@ -82,9 +83,38 @@ def test_qwen_train_step_fits_one_v5e(one_chip):
              "weights": jax.ShapeDtypeStruct((1, 4), jnp.float32)}
     step = jax.jit(make_train_step(model, total_steps=100),
                    donate_argnums=(0, 1))
-    compiled = step.lower(on_chip(params), on_chip(opt),
-                          on_chip(batch)).compile()
-    assert 0 < _peak_bytes(compiled) < V5E_HBM_BYTES
+    return step.lower(on_chip(params), on_chip(opt),
+                      on_chip(batch)).compile()
+
+
+def test_qwen_train_step_fits_one_v5e(qwen_step):
+    """The step compiles for v5e and fits its HBM."""
+    assert 0 < _peak_bytes(qwen_step) < V5E_HBM_BYTES
+
+
+def test_qwen_train_step_matmuls_are_scoped_on_v5e(qwen_step):
+    """Every instruction of the v5e program that runs a matmul, as most
+    run inside fusions, gets a layer from ``repro.obs.hlo_scopes``: what
+    the profiler trace's operations are joined to."""
+    import re
+
+    from repro.obs import hlo_scopes
+
+    text = qwen_step.as_text()
+    _, scopes = hlo_scopes(text)
+    with_matmul = set()
+    for body in re.split(r"\n(?=(?:ENTRY )?%)", text):
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) ", body)
+        if head and re.search(r" (?:dot|convolution)\(", body):
+            with_matmul.add(head.group(1))
+    callers = re.findall(r"^\s+(?:ROOT )?%([\w.\-]+) = .*calls=%([\w.\-]+)",
+                         text, re.M)
+    matmuls = [name for name, called in callers if called in with_matmul]
+    matmuls += re.findall(
+        r"^\s+(?:ROOT )?%([\w.\-]+) = \S+ (?:dot|convolution)\(", text, re.M)
+    assert len(matmuls) >= 10
+    assert [m for m in matmuls if scopes[m] is None] == []
+    assert {scopes[m] for m in matmuls} == {"attention", "mlp", "head"}
 
 
 def test_int8_ef_sync_compiles_over_v5e_mesh(topo, monkeypatch):
