@@ -57,6 +57,7 @@ program (partitioner, collectives, HLO) a TPU pod would run.
 """
 from __future__ import annotations
 
+import dataclasses
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any
 
@@ -163,6 +164,9 @@ class MeshExecutor(SpareTrainer):
         self._ef_state = None
         self._ef_snapshot = None
         self._layout = None
+        # the step is split over the mesh here, not by the model: keep
+        # Pallas attention (which GSPMD cannot partition) off its path
+        self.model = dataclasses.replace(self.model, partitioned=True)
         if sync == "shard_map":
             acc = jnp.dtype(cfg.grad_accum_dtype)
             gtree = jax.tree.map(
@@ -292,10 +296,7 @@ class MeshExecutor(SpareTrainer):
             # inspection can warm the cache outside any run); a run's
             # report counts only the compiles that run triggered
             self.total_recompiles += 1
-            if report is not None:
-                report.recompiles += 1
-            if self.telemetry is not None:
-                self.telemetry.counter("train.recompiles").inc()
+            self._count_compile(report)
         return self._jitted[key]
 
     # ------------------------------------------------------------- #

@@ -5,11 +5,16 @@ when JAX's default backend is the TPU, and then each wrapper compiles
 its kernel with Mosaic; elsewhere ``interpret`` defaults to True and the
 same kernel body runs in Python (the CPU correctness path).
 
-Only :func:`int8_ef_quantize` has a caller on a production path:
-:func:`repro.dist.collectives.compress_grad_int8` picks it when
-``on_tpu()`` holds (the ``grad_compress="int8_ef"`` mesh sync) and its
-jnp oracle otherwise. The model layers call no kernel here; flash
-attention, the SSD scan and rmsnorm run their pure-jnp spellings.
+Of these wrappers only :func:`int8_ef_quantize` has a caller on a
+production path: :func:`repro.dist.collectives.compress_grad_int8` picks
+it when ``on_tpu()`` holds (the ``grad_compress="int8_ef"`` mesh sync)
+and its jnp oracle otherwise. The model's GQA attention runs a kernel
+too, but not one of these: where ``on_tpu()`` holds and the shapes fit,
+:func:`repro.models.attention.gqa_forward` calls splash attention's
+causal MQA kernel (forward and backward, shipped with jax) through
+:func:`repro.models.attention.attend_flash`. The forward-only
+:func:`flash_attention`, the SSD scan and rmsnorm have no model caller;
+those layers run their pure-jnp spellings.
 """
 from __future__ import annotations
 

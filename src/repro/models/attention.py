@@ -1,10 +1,13 @@
-"""Attention: GQA (chunked-causal flash-style reference) and DeepSeek MLA.
+"""Attention: GQA (causal flash kernel on the TPU, chunked jnp reference)
+and DeepSeek MLA.
 
-Train path uses a query-chunked implementation (O(S * chunk) score memory
-instead of O(S^2)) written so the XLA scheduler sees plain einsums — the
-Pallas flash kernel in ``repro/kernels/flash_attention.py`` implements the
-same contract for the TPU hot path and is validated against
-:func:`attend_chunked` (its pure-jnp oracle lives in ``kernels/ref.py``).
+Full-sequence GQA (:func:`gqa_forward`, training and fused prefill) runs
+splash attention's causal MQA kernel (:func:`attend_flash`, Pallas, with
+its own backward) when :func:`flash_blocks` admits the shapes: on the
+TPU, unpartitioned, at a head size and a length the kernel tiles.
+Elsewhere it runs the query-chunked :func:`attend_chunked` (O(S * chunk)
+score memory, plain einsums), which stays the CPU path and the kernel's
+oracle.
 
 Decode path scores one query against a (possibly sequence-sharded) KV
 cache; softmax over the sharded key axis lowers to all-reduce(max)/(sum) —
@@ -16,15 +19,19 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as splash, splash_attention_mask as splash_mask)
+
+from repro.kernels import ops as kernel_ops
 
 from .config import ModelConfig
 from .layers import apply_rope, rmsnorm
 
 __all__ = [
-    "attend_chunked", "gqa_forward", "gqa_decode", "mla_forward",
-    "mla_decode", "KVCache", "MLACache", "init_gqa_cache", "init_mla_cache",
-    "init_gqa_pool", "init_mla_pool", "paged_view", "gqa_decode_paged",
-    "mla_decode_paged",
+    "attend_chunked", "attend_flash", "flash_blocks", "gqa_forward",
+    "gqa_decode", "mla_forward", "mla_decode", "KVCache", "MLACache",
+    "init_gqa_cache", "init_mla_cache", "init_gqa_pool", "init_mla_pool",
+    "paged_view", "gqa_decode_paged", "mla_decode_paged",
 ]
 
 _NEG_INF = -2.0 ** 20  # large-but-finite: keeps bf16/softmax NaN-free
@@ -77,6 +84,98 @@ def attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.transpose(1, 0, 3, 2, 4).reshape(b, s, h, dh)
 
 
+# Block edge of every flash kernel (forward, dq, dkv). On a v5e chip at
+# B 4, H 16, KV 2, S 1024, dh 128 it beat 128 and 256 in each kernel and
+# 1024 overall (PERF.md, section 6). Splash's fused backward was faster,
+# but it rounds each key block's partial dq to bf16 before summing them.
+FLASH_BLOCK = 512
+
+
+def flash_blocks(s: int, head_dim: int,
+                 partitioned: bool) -> splash.BlockSizes | None:
+    """Block sizes of :func:`attend_flash` for a causal self-attention
+    over ``s`` positions, or None where :func:`gqa_forward` keeps
+    :func:`attend_chunked`: off the TPU; where the step is ``partitioned``
+    over devices (a Mosaic call cannot be split by GSPMD); at a head size
+    off the 128-lane tile; at a length the block does not divide.
+    Every block is ``min(s, FLASH_BLOCK)``.
+    """
+    if partitioned or head_dim % 128 or not kernel_ops.on_tpu():
+        return None
+    block = min(s, FLASH_BLOCK)
+    if block % 128 or s % block:
+        return None
+    return splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+
+
+def attend_flash(q: jax.Array, k: jax.Array, v: jax.Array,
+                 blocks: splash.BlockSizes,
+                 interpret: bool = False) -> jax.Array:
+    """Causal GQA attention through splash attention's MQA kernels.
+
+    q: (B, S, H, dh); k, v: (B, S, KV, dh), not repeated. The kernels run
+    once per batch row and KV head over that head's H/KV query heads;
+    they keep scores in VMEM with fp32 accumulation and an fp32 softmax
+    and skip the blocks the causal mask hides. Splash applies no softmax
+    scale, so q is scaled in fp32 first. Returns (B, S, H, dh) in q's
+    dtype.
+
+    Splash's own backward takes ``di = rowsum(dO * O)`` from the output
+    rounded to q's dtype; in bf16 that leaves each query's score
+    gradients a nonzero sum over keys, which the jnp path keeps at zero
+    (measured on the chip as a doubled gap in the key bias's update,
+    PERF.md section 6). So the forward kernel runs on an fp32 q and keeps
+    its fp32 output for ``di``; dq and dkv run splash's backward kernels
+    on the bf16 operands.
+    """
+    b, s, h, dh = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    mask = splash_mask.MultiHeadMask([splash_mask.CausalMask((s, s))] * g)
+    kernel = splash.make_splash_mqa_single_device(
+        mask, block_sizes=blocks, save_residuals=True, interpret=interpret)
+    q = (q.astype(jnp.float32) * dh ** -0.5).astype(q.dtype)
+    q = q.reshape(b, s, kv, g, dh).transpose(0, 2, 3, 1, 4)
+    per_head = jax.vmap(_flash_core, in_axes=(None, 0, 0, 0))
+    out = jax.vmap(per_head, in_axes=(None, 0, 0, 0))(
+        kernel, q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    # (B, KV, G, S, dh) -> (B, S, H, dh)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, dh)
+
+
+@jax.custom_vjp
+def _flash_core(kernel, q, k, v):
+    """One KV head: q (G, S, dh), k and v (S, dh)."""
+    return _flash_fwd(kernel, q, k, v)[0]
+
+
+def _flash_fwd(kernel, q, k, v):
+    o, (lse,) = kernel(q.astype(jnp.float32), k, v)
+    return o.astype(q.dtype), (kernel, q, k, v, o, lse)
+
+
+def _flash_bwd(res, do):
+    kernel, q, k, v, o, lse = res
+    kw = kernel.kwargs
+    # the block masks as splash's own jitted entry hands them on
+    dq_info, dkv_info = (
+        m if m.partial_mask_blocks is None else m._replace(
+            partial_mask_blocks=m.partial_mask_blocks.reshape(
+                -1, *m.partial_mask_blocks.shape[-2:]))
+        for m in (kernel.dq_mask_info, kernel.dkv_mask_info))
+    grads = splash._splash_attention_bwd(
+        False, kw["mask_value"], True, kw["block_sizes"], None,
+        kw["mask_function"], None, kw["interpret"],
+        (q, k, v, None, None, o, lse, dq_info, dkv_info), do)
+    return (None, *grads[3:6])
+
+
+_flash_core.defvjp(_flash_fwd, _flash_bwd)
+
+
 # ------------------------------------------------------------------ #
 # GQA                                                                 #
 # ------------------------------------------------------------------ #
@@ -111,8 +210,14 @@ def _qkv(x, p, cfg: ModelConfig):
 def gqa_forward(x: jax.Array, p: dict, cfg: ModelConfig,
                 positions: jax.Array | None = None,
                 chunk: int = 512, head_constrain=None,
-                return_kv: bool = False):
+                return_kv: bool = False, partitioned: bool = False):
     """Full-sequence causal GQA. x: (B, S, D) -> (B, S, D).
+
+    The attention core runs :func:`attend_flash` where
+    :func:`flash_blocks` admits the shapes (K and V stay unrepeated),
+    else :func:`attend_chunked` over K and V repeated to H heads.
+    ``partitioned`` says the caller splits the step over devices without
+    ``head_constrain`` (``Model.partitioned``); either refuses the kernel.
 
     ``head_constrain`` pins (B, S, H, dh) tensors to head-sharding over
     the model axis (implicitly padded for H % TP != 0). Without it GSPMD
@@ -134,13 +239,18 @@ def gqa_forward(x: jax.Array, p: dict, cfg: ModelConfig,
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     cache = KVCache(k, v) if return_kv else None
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-    k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
-    if head_constrain is not None:
-        q, k, v = head_constrain(q), head_constrain(k), head_constrain(v)
-    out = attend_chunked(q, k, v, chunk=chunk)
-    if head_constrain is not None:
-        out = head_constrain(out)
+    blocks = flash_blocks(s, cfg.resolved_head_dim,
+                          partitioned or head_constrain is not None)
+    if blocks is not None:
+        out = attend_flash(q, k, v, blocks)
+    else:
+        n_rep = cfg.n_heads // cfg.n_kv_heads
+        k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
+        if head_constrain is not None:
+            q, k, v = head_constrain(q), head_constrain(k), head_constrain(v)
+        out = attend_chunked(q, k, v, chunk=chunk)
+        if head_constrain is not None:
+            out = head_constrain(out)
     y = jnp.dot(out.reshape(b, s, -1), p["wo"])
     if return_kv:
         return y, cache

@@ -182,7 +182,10 @@ class Model:
 
     ``mesh``/``dp_axes`` drive the expert-parallel MoE path and the
     activation sharding constraints; None falls back to the single-device
-    reference behavior (tests, smoke configs).
+    reference behavior (tests, smoke configs). ``partitioned`` says a
+    caller splits the step over devices itself (``repro.exec``'s
+    ``MeshExecutor``) without giving the model a mesh; with a mesh or
+    with it, GQA attention keeps its jnp path (:meth:`flash_layers`).
     """
 
     cfg: ModelConfig
@@ -190,6 +193,19 @@ class Model:
     dp_axes: tuple[str, ...] = ("data",)
     ep_axis: str = "model"
     attn_chunk: int = 1024
+    partitioned: bool = False
+
+    def flash_layers(self, seq: int) -> int:
+        """How many attention layers of a forward over ``seq`` positions
+        run the flash kernel: every GQA layer where
+        :func:`repro.models.attention.flash_blocks` admits the shapes,
+        else none."""
+        cfg = self.cfg
+        if cfg.attn_kind == "mla" or attn.flash_blocks(
+                seq, cfg.resolved_head_dim,
+                self.partitioned or self.mesh is not None) is None:
+            return 0
+        return sum(k.startswith("attn") for k in cfg.block_kinds())
 
     # ---------------- sharding constraints ---------------- #
     def _batch_axes(self, batch: int):
@@ -309,7 +325,8 @@ class Model:
                                                        None)
                     x = x + attn.gqa_forward(h, p["attn"], cfg, positions,
                                              chunk=self.attn_chunk,
-                                             head_constrain=hc)
+                                             head_constrain=hc,
+                                             partitioned=self.partitioned)
             else:
                 x = x + ssm_mod.mamba_forward(h, p["mamba"], cfg)
         return self._mlp_part(x, p, kind)
@@ -347,7 +364,8 @@ class Model:
                     y, cache = attn.gqa_forward(h, p["attn"], cfg, positions,
                                                 chunk=self.attn_chunk,
                                                 head_constrain=hc,
-                                                return_kv=True)
+                                                return_kv=True,
+                                                partitioned=self.partitioned)
             else:
                 y, cache = ssm_mod.mamba_forward(h, p["mamba"], cfg,
                                                  return_cache=True)

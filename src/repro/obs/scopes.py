@@ -61,8 +61,10 @@ def hlo_scopes(hlo_text: str) -> tuple[str | None, dict[str, str | None]]:
     An instruction whose own ``op_name`` names no scope but that calls a
     computation (a fusion whose root carries no metadata, as a bitcast
     does) takes the scope most instructions of that computation name.
+    An instruction may span lines: a Pallas kernel's custom call holds
+    newlines in its frontend attributes, and its metadata follows them.
     """
-    module, comp = None, None
+    module, comp, name = None, None, None
     scopes: dict[str, str | None] = {}
     calls: dict[str, str] = {}
     inside: dict[str, Counter] = defaultdict(Counter)
@@ -74,19 +76,23 @@ def hlo_scopes(hlo_text: str) -> tuple[str | None, dict[str, str | None]]:
                 continue
         m = _COMPUTATION.match(line)
         if m:
-            comp = m.group(1)
+            comp, name = m.group(1), None
             continue
         m = _INSTRUCTION.match(line)
-        if not m:
+        if m:
+            name = m.group(1)
+            scopes[name] = None
+        elif name is None or line.strip() in ("", "}"):
+            name = None
             continue
         op = _OP_NAME.search(line)
-        scope = scope_of(op.group(1)) if op else None
-        scopes[m.group(1)] = scope
-        if scope is not None:
-            inside[comp][scope] += 1
+        if op and scopes[name] is None:
+            scopes[name] = scope_of(op.group(1))
+            if scopes[name] is not None:
+                inside[comp][scopes[name]] += 1
         called = _CALLS.search(line)
         if called:
-            calls[m.group(1)] = called.group(1)
+            calls[name] = called.group(1)
     for name, comp in calls.items():
         if scopes[name] is None and inside[comp]:
             scopes[name] = inside[comp].most_common(1)[0][0]

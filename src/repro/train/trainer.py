@@ -218,11 +218,20 @@ class SpareTrainer:
     def _compiled(self, s_a: int, report: TrainReport | None = None):
         if s_a not in self._jitted:
             self._jitted[s_a] = jax.jit(self._step_fn, donate_argnums=(0, 1))
-            if report is not None:
-                report.recompiles += 1
-            if self.telemetry is not None:
-                self.telemetry.counter("train.recompiles").inc()
+            self._count_compile(report)
         return self._jitted[s_a]
+
+    def _count_compile(self, report: TrainReport | None) -> None:
+        """Account one new step program: the run's and the telemetry's
+        recompile counts, and how many attention layers it runs through
+        the flash kernel (``train.attention_kernel_layers``)."""
+        if report is not None:
+            report.recompiles += 1
+        tel = self.telemetry
+        if tel is not None:
+            tel.counter("train.recompiles").inc()
+            tel.gauge("train.attention_kernel_layers").set(
+                self.model.flash_layers(self.pipeline.seq))
 
     def _step_batch(self, state, step: int) -> dict:
         """Step ``step``'s batch under ``state``'s schedule, on device."""

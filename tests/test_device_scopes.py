@@ -73,14 +73,20 @@ def test_hlo_scopes_reads_module_and_instructions():
             'metadata={op_name="jit(step)/jvp(mlp)/dot_general"}\n'
             '  %fusion.6 = f32[2]{0} fusion(%dot.1), kind=kLoop, '
             'calls=%fused_computation\n'
+            '  %splash_mqa_fwd.7 = f32[2]{0} custom-call(%p), '
+            'custom_call_target="tpu_custom_call", frontend_attributes='
+            '{kernel_metadata={"xprof_metadata":"{\\"block_q\\": 512\n'
+            '}}, metadata={op_name="jit(step)/attention/vmap(jit('
+            '_splash_attention))/pallas_call"}\n'
             '  ROOT %add.2 = f32[2]{0} add(%fusion.6, %p), '
             'metadata={op_name="jit(step)/add"}\n}\n')
     module, scopes = hlo_scopes(text)
     assert module == "jit_step"
-    # the fusion has no op_name of its own: its body's scope is taken
+    # the fusion has no op_name of its own: its body's scope is taken;
+    # the kernel's custom call spans two lines, its metadata on the second
     assert scopes == {"q": None, "neg.4": "head", "bitcast.5": None,
                       "p": None, "dot.1": "mlp", "fusion.6": "head",
-                      "add.2": None}
+                      "splash_mqa_fwd.7": "attention", "add.2": None}
 
 
 def test_every_matmul_of_the_train_step_is_scoped(step_ops):

@@ -158,3 +158,80 @@ def test_int8_ef_sync_compiles_over_v5e_mesh(topo, monkeypatch):
     for dims, op in int8_colls:
         assert int(dims.split(",")[-1]) == CompressedBucketSync.LANES, \
             f"int8 {op} of s8[{dims}] is not lane-aligned"
+
+
+# the training cell's attention: qwen2.5-3b widths, N=4 groups of one
+# 1024-token sequence a stack
+CELL_ATTN = dict(b=4, h=16, kv=2, s=1024, dh=128)
+
+
+def test_flash_attention_fwd_bwd_compile_for_v5e(one_chip, monkeypatch):
+    """The kernel path of ``gqa_forward`` at the training cell's shapes,
+    forward and backward, lowered by Mosaic: the forward, dq and dkv
+    kernels are custom calls of the compiled program."""
+    import re
+
+    import repro.kernels.ops as ops
+    from repro.models.attention import attend_flash, flash_blocks
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    b, h, kv, s, dh = (CELL_ATTN[k] for k in ("b", "h", "kv", "s", "dh"))
+    blocks = flash_blocks(s, dh, partitioned=False)
+    assert blocks is not None
+
+    def loss(q, k, v):
+        return jnp.sum(attend_flash(q, k, v, blocks).astype(jnp.float32))
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, s, heads, dh), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(h), arg(kv), arg(kv)).compile().as_text()
+    names = set(re.findall(r"splash_mqa_(fwd|dq|dkv)", text))
+    assert names == {"fwd", "dq", "dkv"}
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def cell_step(one_chip):
+    """The training cell's step (qwen2.5-3b widths, 4 layers, S_A = 2
+    stacks of N=4 groups of one 1024-token sequence), compiled for v5e
+    with the attention kernel chosen as on the chip."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.optim import adamw_init
+    from repro.train.step import make_train_step
+
+    import repro.kernels.ops as ops
+
+    cfg = get_config("qwen2.5-3b").scaled(n_layers=4, grad_accum=1)
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(adamw_init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 4, 1024), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((2, 4, 1024), jnp.int32),
+             "weights": jax.ShapeDtypeStruct((2, 4), jnp.float32)}
+    step = jax.jit(make_train_step(model, total_steps=100),
+                   donate_argnums=(0, 1))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_tpu", lambda: True)
+        assert model.flash_layers(1024) == 4
+        return step.lower(on_chip(params), on_chip(opt),
+                          on_chip(batch)).compile()
+
+
+def test_cell_step_with_flash_attention_fits_one_v5e(cell_step):
+    """With the kernel on its path the cell's step fits 16 GiB, runs the
+    forward, dq and dkv kernels, and holds no fp32 (B, H, S, S) score
+    tensor."""
+    text = cell_step.as_text()
+    assert 0 < _peak_bytes(cell_step) < V5E_HBM_BYTES
+    for phase in ("fwd", "dq", "dkv"):
+        assert f"splash_mqa_{phase}" in text
+    assert "f32[4,16,1024,1024]" not in text
