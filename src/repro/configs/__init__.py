@@ -70,5 +70,6 @@ def smoke_config(arch: str) -> ModelConfig:
         )
     if cfg.ssm is not None:
         kw["ssm"] = SSMConfig(d_state=16, head_dim=8, expand=2,
-                              conv_width=4, n_groups=1, chunk=32)
+                              conv_width=4, n_groups=1, chunk=32,
+                              residual_in_fp32=cfg.ssm.residual_in_fp32)
     return replace(cfg, **kw)

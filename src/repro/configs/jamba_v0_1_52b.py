@@ -21,7 +21,7 @@ CONFIG = ModelConfig(
     hybrid_period=8,
     hybrid_attn_pos=4,
     ssm=SSMConfig(d_state=16, head_dim=64, expand=2, conv_width=4,
-                  n_groups=1, chunk=256),
+                  n_groups=1, chunk=256, residual_in_fp32=False),
     moe=MoEConfig(
         n_experts=16,
         top_k=2,

@@ -1,5 +1,6 @@
-"""mamba2-1.3b [ssm] — 48L d_model=2048 attention-free vocab=50280 (tied
-embeddings), SSD d_state=128 head_dim=64 expand=2 [arXiv:2405.21060]."""
+"""mamba2-1.3b [ssm] — 48L d_model=2048 attention-free vocab=50277 (tied
+embeddings, table padded to 50688 rows), SSD d_state=128 head_dim=64
+expand=2 [arXiv:2405.21060]."""
 from repro.models.config import ModelConfig, SSMConfig
 
 CONFIG = ModelConfig(
@@ -10,7 +11,7 @@ CONFIG = ModelConfig(
     n_heads=0,
     n_kv_heads=0,
     d_ff=0,
-    vocab=50280,
+    vocab=50277,
     tie_embeddings=True,
     ssm=SSMConfig(d_state=128, head_dim=64, expand=2, conv_width=4,
                   n_groups=1, chunk=256),

@@ -34,6 +34,9 @@ class SSMConfig:
     conv_width: int = 4
     n_groups: int = 1
     chunk: int = 256               # SSD chunk length
+    # the residual stream in fp32, mixers fed in the compute dtype
+    # (mamba_ssm's ``residual_in_fp32``, true in Mamba-2's configs)
+    residual_in_fp32: bool = True
 
     def d_inner(self, d_model: int) -> int:
         return self.expand * d_model
@@ -116,6 +119,11 @@ class ModelConfig:
         """True if decode cost per token is o(seq) in attention state —
         SSM and hybrid families qualify for long_500k."""
         return self.family in ("ssm", "hybrid")
+
+    @property
+    def residual_fp32(self) -> bool:
+        """Whether the residual stream between blocks is kept in fp32."""
+        return self.ssm is not None and self.ssm.residual_in_fp32
 
     @property
     def has_attention(self) -> bool:

@@ -15,8 +15,12 @@ the scanned xs/ys; the hidden state is the carry).
 
 Each layer runs under a ``jax.named_scope`` of
 :data:`repro.obs.DEVICE_SCOPES` (``embed``, ``attention``/``ssm``,
-``mlp``/``moe``, ``head``), which names its device operations in a
-profiler trace at no run-time cost.
+``mlp``/``moe``, ``head``; ``ssm`` holds the SSD scan's ``ssd``), which
+names its device operations in a profiler trace at no run-time cost.
+
+The residual stream between blocks is fp32 where ``cfg.residual_fp32``
+(Mamba-2's ``residual_in_fp32``), else the compute dtype; every block
+and the head are fed in the compute dtype.
 """
 from __future__ import annotations
 
@@ -290,19 +294,24 @@ class Model:
         return params
 
     # ---------------- blocks ---------------- #
+    def _norm(self, x, w):
+        """RMSNorm of the residual stream, in the compute dtype (the
+        stream itself is fp32 where ``cfg.residual_fp32``)."""
+        return rmsnorm(x, w, self.cfg.norm_eps).astype(ACT_DTYPE)
+
     def _mlp_part(self, x, p, kind):
         _, _, mlp = kind.partition("_")
         if not mlp:
             return x
         if mlp != "dense":
             with jax.named_scope("moe"):
-                h = rmsnorm(x, p["ln2"], self.cfg.norm_eps)
+                h = self._norm(x, p["ln2"])
                 return x + moe_mod.moe_ffn(
                     h, p["moe"], self.cfg, mesh=self.mesh,
                     dp_axes=self.dp_axes, ep_axis=self.ep_axis)
         from .layers import mlp2, swiglu
         with jax.named_scope("mlp"):
-            h = rmsnorm(x, p["ln2"], self.cfg.norm_eps)
+            h = self._norm(x, p["ln2"])
             if self.cfg.mlp_kind != "swiglu":
                 return x + mlp2(h, p["mlp"]["w_in"], p["mlp"]["w_out"],
                                 kind=self.cfg.mlp_kind)
@@ -313,7 +322,7 @@ class Model:
         cfg = self.cfg
         mixer = kind.partition("_")[0]
         with jax.named_scope(_MIXER_SCOPE[mixer]):
-            h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+            h = self._norm(x, p["ln1"])
             if mixer == "attn":
                 if cfg.attn_kind == "mla":
                     x = x + attn.mla_forward(h, p["attn"], cfg, positions,
@@ -335,7 +344,7 @@ class Model:
         cfg = self.cfg
         mixer = kind.partition("_")[0]
         with jax.named_scope(_MIXER_SCOPE[mixer]):
-            h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+            h = self._norm(x, p["ln1"])
             if mixer == "attn":
                 dec = (attn.mla_decode if cfg.attn_kind == "mla"
                        else attn.gqa_decode)
@@ -350,7 +359,7 @@ class Model:
         cfg = self.cfg
         mixer = kind.partition("_")[0]
         with jax.named_scope(_MIXER_SCOPE[mixer]):
-            h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+            h = self._norm(x, p["ln1"])
             if mixer == "attn":
                 if cfg.attn_kind == "mla":
                     y, cache = attn.mla_forward(h, p["attn"], cfg, positions,
@@ -376,7 +385,7 @@ class Model:
         cfg = self.cfg
         mixer = kind.partition("_")[0]
         with jax.named_scope(_MIXER_SCOPE[mixer]):
-            h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+            h = self._norm(x, p["ln1"])
             if mixer == "attn":
                 dec = (attn.mla_decode_paged if cfg.attn_kind == "mla"
                        else attn.gqa_decode_paged)
@@ -397,13 +406,15 @@ class Model:
             else:
                 assert tokens is not None
                 x = embed_lookup(params["embed"], tokens)
+            if self.cfg.residual_fp32:
+                x = x.astype(jnp.float32)
             return self._constrain(x)
 
     def _head(self, params, x) -> jax.Array:
         """Final norm, the tied or untied head and pad masking."""
         cfg = self.cfg
         with jax.named_scope("head"):
-            x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            x = self._norm(x, params["final_norm"])
             head = (params["embed"].T if cfg.tie_embeddings
                     else params["lm_head"])
             return self._mask_pad(jnp.dot(x, head))

@@ -85,15 +85,19 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a_log: jax.Array,
         cum = jnp.cumsum(dtaz, axis=1)                   # (B,Q,H)
         seg_total = cum[:, -1]                           # (B,H)
 
-        # intra-chunk quadratic form: L[i,j] = exp(cum_i - cum_j), j <= i
+        # intra-chunk quadratic form: L[i,j] = exp(cum_i - cum_j), j <= i.
+        # Masked before the exp, as the paper's segment sum is: above the
+        # diagonal logl is positive and overflows fp32 once a chunk's
+        # decay passes ~88, and the backward's 0 * inf would be NaN.
         logl = cum[:, :, None, :] - cum[:, None, :, :]   # (B,Q,Q,H)
-        l = jnp.where(mask[None, :, :, None], jnp.exp(logl), 0.0)
+        l = jnp.exp(jnp.where(mask[None, :, :, None], logl, -jnp.inf))
         cb = jnp.einsum("bihn,bjhn->bijh",
                         cz.astype(jnp.float32), bz.astype(jnp.float32))
         w = cb * l * dtz[:, None, :, :]                  # weight on x_j
         y_intra = jnp.einsum("bijh,bjhp->bihp", w, xz.astype(jnp.float32))
 
-        # inter-chunk: y_inter[i] = exp(cum_i) * c_i . state
+        # inter-chunk: y_inter[i] = exp(cum_i) * c_i . state; this and
+        # the state update's exponents are <= 0 (dt >= 0, A < 0)
         y_inter = jnp.einsum("bihn,bhpn->bihp", cz.astype(jnp.float32), state)
         y_inter = y_inter * jnp.exp(cum)[..., None]
 
@@ -204,8 +208,9 @@ def mamba_forward(x: jax.Array, p: dict, cfg: ModelConfig,
                             + p["dt_bias"].astype(jnp.float32))
 
     chunk = min(s.chunk, seq)
-    y, final = ssd_chunked(xh, dt_sp, p["a_log"], bh, ch, chunk,
-                           state0=state0)
+    with jax.named_scope("ssd"):
+        y, final = ssd_chunked(xh, dt_sp, p["a_log"], bh, ch, chunk,
+                               state0=state0)
     y = y + xh * p["d_skip"].astype(jnp.float32)[None, None, :, None].astype(y.dtype)
     y = y.reshape(bsz, seq, d_in)
     # gated RMSNorm (mamba-2): norm(y * silu(z))

@@ -12,6 +12,7 @@ gives every device-second a layer.
 ``embed``             the embedding lookup
 ``attention``         ln1, the GQA / MLA mixer and its residual add
 ``ssm``               the same for the Mamba mixer
+``ssd``               the Mamba mixer's chunked SSD scan, inside ``ssm``
 ``mlp``               ln2, the dense SwiGLU / MLP and its residual add
 ``moe``               ln2, the routed-expert FFN and its residual add
 ``head``              final norm, the head matmul, pad masking and the
@@ -21,7 +22,7 @@ gives every device-second a layer.
 ``sync``              the gradient all-reduce (per leaf, bucketed, int8)
 ====================  ==================================================
 
-Scopes never nest inside one another on one code path; where a trace
+Only ``ssd`` nests, inside ``ssm``; where a scope nests or a trace
 context wraps one (``jvp(head)``), the innermost vocabulary word of the
 path is the scope.
 """
@@ -32,7 +33,7 @@ from collections import Counter, defaultdict
 
 __all__ = ["DEVICE_SCOPES", "scope_of", "hlo_scopes"]
 
-DEVICE_SCOPES = ("embed", "attention", "ssm", "mlp", "moe", "head",
+DEVICE_SCOPES = ("embed", "attention", "ssm", "ssd", "mlp", "moe", "head",
                  "grad_accum", "optimizer", "sync")
 
 _WORD = re.compile(r"[^/()]+")

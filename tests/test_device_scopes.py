@@ -53,6 +53,7 @@ def step_ops(cfg):
     ("jit(f)/transpose(jvp(head))/dot_general", "head"),
     ("jit(f)/checkpoint/rematted_computation/mlp/dot_general", "mlp"),
     ("jit(train_step)/optimizer/sqrt", "optimizer"),
+    ("jit(f)/transpose(jvp())/while/body/ssm/ssd/while/body/exp", "ssd"),
     ("jit(f)/while/body/dynamic_update_slice", None),
     ("jit(headless)/mlpx/add", None),
 ])
@@ -114,6 +115,28 @@ def test_scopes_are_the_vocabulary(step_ops):
     assert found == {"embed", "attention", "mlp", "head", "grad_accum",
                      "optimizer"}
     assert found <= set(DEVICE_SCOPES)
+
+
+def test_ssd_scan_scoped_inside_the_ssm_mixer():
+    """On a tiny Mamba-2 step the SSD scan's operations, forward and
+    backward, land under ``ssd``, and the mixer's projections under
+    ``ssm``."""
+    from repro.configs import smoke_config
+    from repro.train.trainer import SpareTrainer
+    cfg = smoke_config("mamba2-1.3b").scaled(grad_accum=1)
+    text = SpareTrainer(cfg, n_groups=4, redundancy=2, seq=32,
+                        per_type_batch=1,
+                        total_steps=50).compiled_step_text()
+    ops = [(op.group(1), bool(_MATMUL.match(line)))
+           for line in text.splitlines()
+           for op in [_OP_NAME.search(line)] if op]
+    for scope in ("ssm", "ssd"):
+        mine = [op for op, is_mm in ops if is_mm and scope_of(op) == scope]
+        assert any("transpose(" not in op for op in mine), scope
+        assert any("transpose(" in op for op in mine), scope
+    assert all("/ssm/" in op for op, _ in ops if scope_of(op) == "ssd")
+    assert {scope_of(op) for op, is_mm in ops if is_mm} == {
+        "ssm", "ssd", "head"}
 
 
 def test_serve_programs_are_named(cfg):

@@ -141,3 +141,133 @@ def test_ssd_state_continuation(nchunks, chunk):
     np.testing.assert_allclose(
         jnp.concatenate([y1, y2], axis=1), y_full, atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(st2, st_full, atol=1e-4, rtol=1e-4)
+
+
+# The published init (A in [1, 16], dt in [0.001, 0.1]) at chunk 256: a
+# head's decay summed over a chunk reaches 16 * 0.1 * 256 = 410, and
+# exp(cum_i - cum_j) above the diagonal overflows fp32 past about 88.
+OVERFLOW_DT = [0.05, 0.1]                # decay over the chunk: 205, 410
+
+
+def _witness(dt_value, s=256, h=2, p=8, n=16):
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.normal(size=(1, s, h, p)), jnp.float32)
+    dt = jnp.full((1, s, h), dt_value, jnp.float32)
+    a_log = jnp.full((h,), np.log(16.0), jnp.float32)
+    bb = jnp.asarray(rng.normal(size=(1, s, h, n)), jnp.float32)
+    cc = jnp.asarray(rng.normal(size=(1, s, h, n)), jnp.float32)
+    ry = jnp.asarray(rng.normal(size=(1, s, h, p)), jnp.float32)
+    rs = jnp.asarray(rng.normal(size=(1, h, p, n)), jnp.float32)
+    return (x, dt, a_log, bb, cc), (ry, rs)
+
+
+def _stepwise(x, dt, a_log, bb, cc):
+    """The recurrence one token at a time (``ssd_decode_step``)."""
+    state = jnp.zeros((x.shape[0], x.shape[2], x.shape[3], bb.shape[-1]),
+                      jnp.float32)
+
+    def step(state, t):
+        y, state = ssd_decode_step(t[0], t[1], a_log, t[2], t[3], state)
+        return state, y
+    final, ys = jax.lax.scan(
+        step, state, tuple(jnp.moveaxis(t, 1, 0) for t in (x, dt, bb, cc)))
+    return jnp.moveaxis(ys, 0, 1), final
+
+
+def _grads(fn, args, weights):
+    ry, rs = weights
+
+    def loss(*a):
+        y, final = fn(*a)
+        return jnp.sum(y * ry) + jnp.sum(final * rs)
+    return jax.grad(loss, argnums=tuple(range(5)))(*args)
+
+
+@pytest.mark.parametrize("dt_value", OVERFLOW_DT)
+def test_ssd_chunked_backward_finite_at_published_decay(dt_value):
+    """One chunk of 256 whose decay overflows exp above the diagonal:
+    the gradients of x, dt, a_log, b and c are finite and equal the
+    stepwise recurrence's within fp32 rounding.
+
+    The chunked form takes each decay as a difference of cumulative
+    sums that reach 410, whose fp32 spacing is 3e-5: gradients read at
+    most 1e-4 of their largest element off the stepwise ones (da_log,
+    a sum over every pair of positions), so 2e-4 of it is allowed."""
+    args, weights = _witness(dt_value)
+    chunked = _grads(lambda *a: ssd_chunked(*a, chunk=256), args, weights)
+    stepwise = _grads(_stepwise, args, weights)
+    for name, g, ref in zip(("x", "dt", "a_log", "b", "c"), chunked,
+                            stepwise):
+        assert bool(jnp.all(jnp.isfinite(g))), f"d{name} not finite"
+        scale = float(jnp.max(jnp.abs(ref)))
+        np.testing.assert_allclose(g, ref, rtol=0, atol=2e-4 * scale,
+                                   err_msg=f"d{name}")
+
+
+def _ssd_exp_then_mask(x, dt, a_log, b, c, chunk):
+    """``ssd_chunked``'s forward as it read before the log-decay was
+    masked ahead of the exponential (``where(mask, exp(logl), 0)``)."""
+    bs, s, h, p = x.shape
+    n = b.shape[-1]
+    nc = s // chunk
+    a = -jnp.exp(a_log.astype(jnp.float32))
+    xr = x.reshape(bs, nc, chunk, h, p).transpose(1, 0, 2, 3, 4)
+    br = b.reshape(bs, nc, chunk, h, n).transpose(1, 0, 2, 3, 4)
+    cr = c.reshape(bs, nc, chunk, h, n).transpose(1, 0, 2, 3, 4)
+    dtr = dt.reshape(bs, nc, chunk, h).transpose(1, 0, 2, 3)
+    mask = jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :]
+
+    def body(state, inp):
+        xz, bz, cz, dtz = inp
+        cum = jnp.cumsum(dtz * a[None, None, :], axis=1)
+        seg_total = cum[:, -1]
+        logl = cum[:, :, None, :] - cum[:, None, :, :]
+        l = jnp.where(mask[None, :, :, None], jnp.exp(logl), 0.0)
+        cb = jnp.einsum("bihn,bjhn->bijh", cz, bz)
+        w = cb * l * dtz[:, None, :, :]
+        y_intra = jnp.einsum("bijh,bjhp->bihp", w, xz)
+        y_inter = jnp.einsum("bihn,bhpn->bihp", cz, state)
+        y_inter = y_inter * jnp.exp(cum)[..., None]
+        dec_to_end = jnp.exp(seg_total[:, None, :] - cum)
+        s_chunk = jnp.einsum("bjh,bjhn,bjhp->bhpn", dec_to_end * dtz, bz, xz)
+        new_state = state * jnp.exp(seg_total)[:, :, None, None] + s_chunk
+        return new_state, y_intra + y_inter
+
+    final, ys = jax.lax.scan(body, jnp.zeros((bs, h, p, n), jnp.float32),
+                             (xr, br, cr, dtr))
+    return ys.transpose(1, 0, 2, 3, 4).reshape(bs, s, h, p), final
+
+
+@pytest.mark.parametrize("dt_value,chunk", [(0.01, 64), (0.05, 256),
+                                            (0.1, 256)])
+def test_ssd_chunked_forward_unchanged_by_the_mask(dt_value, chunk):
+    """exp(-inf) is exactly 0: masking the log-decay first leaves the
+    forward bit for bit as the exp-then-mask formula gave it, which is
+    finite here."""
+    args, _ = _witness(dt_value)
+    y, final = ssd_chunked(*args, chunk=chunk)
+    y_old, final_old = _ssd_exp_then_mask(*args, chunk=chunk)
+    assert bool(jnp.all(jnp.isfinite(y_old)))
+    np.testing.assert_array_equal(y, y_old)
+    np.testing.assert_array_equal(final, final_old)
+
+
+@pytest.mark.parametrize("arch,dtype", [("mamba2-1.3b", jnp.float32),
+                                        ("jamba-v0.1-52b", jnp.bfloat16),
+                                        ("qwen2.5-3b", jnp.bfloat16)])
+def test_residual_stream_dtype(arch, dtype):
+    """Mamba-2's residual stream between blocks is fp32 as its config
+    states (``residual_in_fp32``); Jamba's and the dense models' stay in
+    the compute dtype. The head is fed in the compute dtype either way."""
+    from repro.configs import smoke_config
+    from repro.models import build_model
+    cfg = smoke_config(arch)
+    assert cfg.residual_fp32 is (dtype == jnp.float32)
+    m = build_model(cfg)
+    params = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    x = jax.eval_shape(lambda p, t: m._embed(p, t, None), params, tokens)
+    assert x.dtype == dtype
+    logits = jax.eval_shape(lambda p, t: m.forward(p, tokens=t), params,
+                            tokens)
+    assert logits.dtype == jnp.bfloat16

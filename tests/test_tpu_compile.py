@@ -40,6 +40,30 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_step(cfg, one_chip, stacks: int, seq: int):
+    """The single-chip SPARe train step of ``cfg`` over ``stacks`` stacks
+    of N=4 groups of one ``seq``-token sequence, compiled for v5e."""
+    from repro.models import build_model
+    from repro.optim import adamw_init
+    from repro.train.step import make_train_step
+
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(adamw_init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((stacks, 4, seq), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((stacks, 4, seq), jnp.int32),
+             "weights": jax.ShapeDtypeStruct((stacks, 4), jnp.float32)}
+    step = jax.jit(make_train_step(model, total_steps=100),
+                   donate_argnums=(0, 1))
+    return step.lower(on_chip(params), on_chip(opt),
+                      on_chip(batch)).compile()
+
+
 def _peak_bytes(compiled) -> int:
     m = compiled.memory_analysis()
     return (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -65,26 +89,9 @@ def qwen_step(one_chip):
     width (1 layer; N=4 groups of one 1024-token sequence), compiled for
     v5e."""
     from repro.configs import get_config
-    from repro.models import build_model
-    from repro.optim import adamw_init
-    from repro.train.step import make_train_step
 
     cfg = get_config("qwen2.5-3b").scaled(n_layers=1, grad_accum=1)
-    model = build_model(cfg)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
-
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    opt = jax.eval_shape(adamw_init, params)
-    batch = {"tokens": jax.ShapeDtypeStruct((1, 4, 1024), jnp.int32),
-             "labels": jax.ShapeDtypeStruct((1, 4, 1024), jnp.int32),
-             "weights": jax.ShapeDtypeStruct((1, 4), jnp.float32)}
-    step = jax.jit(make_train_step(model, total_steps=100),
-                   donate_argnums=(0, 1))
-    return step.lower(on_chip(params), on_chip(opt),
-                      on_chip(batch)).compile()
+    return _compiled_step(cfg, one_chip, stacks=1, seq=1024)
 
 
 def test_qwen_train_step_fits_one_v5e(qwen_step):
@@ -200,30 +207,14 @@ def cell_step(one_chip):
     with the attention kernel chosen as on the chip."""
     from repro.configs import get_config
     from repro.models import build_model
-    from repro.optim import adamw_init
-    from repro.train.step import make_train_step
 
     import repro.kernels.ops as ops
 
     cfg = get_config("qwen2.5-3b").scaled(n_layers=4, grad_accum=1)
-    model = build_model(cfg)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
-
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    opt = jax.eval_shape(adamw_init, params)
-    batch = {"tokens": jax.ShapeDtypeStruct((2, 4, 1024), jnp.int32),
-             "labels": jax.ShapeDtypeStruct((2, 4, 1024), jnp.int32),
-             "weights": jax.ShapeDtypeStruct((2, 4), jnp.float32)}
-    step = jax.jit(make_train_step(model, total_steps=100),
-                   donate_argnums=(0, 1))
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "on_tpu", lambda: True)
-        assert model.flash_layers(1024) == 4
-        return step.lower(on_chip(params), on_chip(opt),
-                          on_chip(batch)).compile()
+        assert build_model(cfg).flash_layers(1024) == 4
+        return _compiled_step(cfg, one_chip, stacks=2, seq=1024)
 
 
 def test_cell_step_with_flash_attention_fits_one_v5e(cell_step):
@@ -235,3 +226,47 @@ def test_cell_step_with_flash_attention_fits_one_v5e(cell_step):
     for phase in ("fwd", "dq", "dkv"):
         assert f"splash_mqa_{phase}" in text
     assert "f32[4,16,1024,1024]" not in text
+
+
+@pytest.fixture(scope="module")
+def mamba_cell_step(one_chip):
+    """The Mamba-2 training cell's step (mamba2-1.3b widths, 12 layers,
+    S_A = 2 stacks of N=4 groups of one 2048-token sequence), compiled
+    for v5e."""
+    from repro.configs import get_config
+
+    cfg = get_config("mamba2-1.3b").scaled(n_layers=12, grad_accum=1)
+    return _compiled_step(cfg, one_chip, stacks=2, seq=2048)
+
+
+def test_mamba_cell_step_fits_one_v5e(mamba_cell_step):
+    assert 0 < _peak_bytes(mamba_cell_step) < V5E_HBM_BYTES
+
+
+def test_mamba_cell_step_scan_is_scoped_on_v5e(mamba_cell_step):
+    """Every matmul of the v5e program carries ``ssm``, ``ssd`` or
+    ``head``, and the SSD scan's, forward and backward, carry ``ssd``.
+    Every exponential of the mixer but its SiLU and softplus is the
+    scan's and carries ``ssd``."""
+    import re
+
+    from repro.obs import scope_of
+
+    found = []
+    for m in re.finditer(r"^\s+(?:ROOT )?%[\w.\-]+ = \S+ "
+                         r"(dot|convolution|exponential)\(.*"
+                         r'op_name="([^"]*)"', mamba_cell_step.as_text(),
+                         re.M):
+        found.append((m.group(1), m.group(2), scope_of(m.group(2))))
+    matmuls = [(op, s) for kind, op, s in found if kind != "exponential"]
+    assert len(matmuls) >= 20
+    assert {s for _, s in matmuls} == {"ssm", "ssd", "head"}
+    ssd = [op for op, s in matmuls if s == "ssd"]
+    assert any("transpose(" not in op for op in ssd), "no forward scan"
+    assert any("transpose(" in op for op in ssd), "no backward scan"
+    mixer_exps = [(op, s) for kind, op, s in found
+                  if kind == "exponential" and s in ("ssm", "ssd")]
+    scan_exps = [(op, s) for op, s in mixer_exps
+                 if not re.search(r"jit\((silu|softplus)\)", op)]
+    assert len(scan_exps) >= 5
+    assert {s for _, s in scan_exps} == {"ssd"}
