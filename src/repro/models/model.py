@@ -211,6 +211,16 @@ class Model:
             return 0
         return sum(k.startswith("attn") for k in cfg.block_kinds())
 
+    def ssd_chunk_blocks(self, batch: int, seq: int) -> int:
+        """How many blocks each SSD call of a forward over ``batch``
+        sequences of ``seq`` positions runs its chunks in
+        (:func:`repro.models.ssm.ssd_blocks`); 0 with no SSM layer."""
+        if not any(k.startswith("mamba") for k in self.cfg.block_kinds()):
+            return 0
+        s = self.cfg.ssm
+        return ssm_mod.ssd_blocks(batch, seq, s.n_heads(self.cfg.d_model),
+                                  min(s.chunk, seq))
+
     # ---------------- sharding constraints ---------------- #
     def _batch_axes(self, batch: int):
         if self.mesh is None:

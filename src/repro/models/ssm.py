@@ -1,10 +1,11 @@
 """Mamba-2 (SSD — state-space duality) block.
 
 Chunked SSD semantics (Dao & Gu 2024): within chunks of length Q the
-recurrence is computed as a masked attention-like quadratic form; across
-chunks a tiny ``lax.scan`` carries the (heads, head_dim, d_state) running
-state. Decode keeps O(1) state per token — which is why the ssm/hybrid
-families are the only ones qualifying for the long_500k shape.
+recurrence is computed as a masked attention-like quadratic form, every
+chunk at once, with C·Bᵀ taken once per group of heads; only the
+(heads, head_dim, d_state) state crosses chunks, in a scan over the
+chunks' own states. Decode keeps O(1) state per token — which is why the
+ssm/hybrid families are the only ones qualifying for the long_500k shape.
 
 Projections are split per component (z/x/B/C/dt) instead of one fused
 in_proj so each weight shards cleanly over the ``model`` axis (heads and
@@ -48,6 +49,21 @@ def init_mamba_cache(cfg: ModelConfig, batch: int,
 # ------------------------------------------------------------------ #
 # SSD core                                                            #
 # ------------------------------------------------------------------ #
+# The most fp32 (Q, Q) working set, B·H·chunks·Q²·4 bytes, one call
+# holds at once; past it the chunks run in blocks (:func:`ssd_blocks`).
+QQ_BUDGET_BYTES = 1 << 30
+
+
+def ssd_blocks(batch: int, seq: int, heads: int, chunk: int) -> int:
+    """How many blocks :func:`ssd_chunked` runs a call's chunks in: the
+    fewest whose (Q, Q) working set fits :data:`QQ_BUDGET_BYTES`, each
+    an equal share of the ``seq // chunk`` chunks."""
+    nc = seq // chunk
+    per_chunk = batch * heads * chunk * chunk * 4
+    return next(nc // k for k in range(nc, 0, -1) if nc % k == 0
+                and (k * per_chunk <= QQ_BUDGET_BYTES or k == 1))
+
+
 def ssd_chunked(x: jax.Array, dt: jax.Array, a_log: jax.Array,
                 b: jax.Array, c: jax.Array, chunk: int,
                 state0: jax.Array | None = None
@@ -57,61 +73,96 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a_log: jax.Array,
     x:  (B, S, H, P)   — per-head inputs (P = head_dim)
     dt: (B, S, H)      — softplus'd timestep
     a_log: (H,)        — A = -exp(a_log)
-    b, c: (B, S, H, N) — input/output projections (already group-broadcast)
+    b, c: (B, S, G, N) — input/output projections of G groups, H % G == 0
+                         (heads g*H/G .. (g+1)*H/G - 1 read group g)
+    state0: (B, H, P, N) — state before the first token, else zeros
     Returns (y (B,S,H,P), final_state (B,H,P,N)).
 
     All decay math in fp32; the recurrence is y_t = c_t . S_t with
-    S_t = exp(dt_t A) S_{t-1} + dt_t b_t (x) x_t.
+    S_t = exp(dt_t A) S_{t-1} + dt_t b_t (x) x_t. Every chunk of a block
+    is computed at once (:func:`_ssd_block`); blocks, one unless the
+    (Q, Q) working set passes :data:`QQ_BUDGET_BYTES`, run in a scan
+    that carries the state.
     """
     bs, s, h, p = x.shape
-    n = b.shape[-1]
+    g, n = b.shape[2:]
     assert s % chunk == 0, f"seq {s} % chunk {chunk} != 0"
-    nc = s // chunk
-
+    assert h % g == 0, f"heads {h} % groups {g} != 0"
     a = -jnp.exp(a_log.astype(jnp.float32))              # (H,)
-    # chunk-major layout for the scan: (nc, B, Q, H, *)
-    xr = x.reshape(bs, nc, chunk, h, p).transpose(1, 0, 2, 3, 4)
-    br = b.reshape(bs, nc, chunk, h, n).transpose(1, 0, 2, 3, 4)
-    cr = c.reshape(bs, nc, chunk, h, n).transpose(1, 0, 2, 3, 4)
-    dtr = dt.reshape(bs, nc, chunk, h).transpose(1, 0, 2, 3).astype(jnp.float32)
-
-    mask = (jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :])
     init = (jnp.zeros((bs, h, p, n), jnp.float32)
             if state0 is None else state0.astype(jnp.float32))
+    nb = ssd_blocks(bs, s, h, chunk)
+    if nb == 1:
+        return _ssd_block(x, dt, a, b, c, chunk, init)
 
-    def scan_body(state, inp):
-        xz, bz, cz, dtz = inp                            # (B,Q,H,*)
-        dtaz = dtz * a[None, None, :]                    # (B,Q,H) log-decay
-        cum = jnp.cumsum(dtaz, axis=1)                   # (B,Q,H)
-        seg_total = cum[:, -1]                           # (B,H)
+    def blocks(t):                                       # (nb, B, S/nb, *)
+        return jnp.moveaxis(t.reshape(bs, nb, s // nb, *t.shape[2:]), 1, 0)
 
-        # intra-chunk quadratic form: L[i,j] = exp(cum_i - cum_j), j <= i.
-        # Masked before the exp, as the paper's segment sum is: above the
-        # diagonal logl is positive and overflows fp32 once a chunk's
-        # decay passes ~88, and the backward's 0 * inf would be NaN.
-        logl = cum[:, :, None, :] - cum[:, None, :, :]   # (B,Q,Q,H)
-        l = jnp.exp(jnp.where(mask[None, :, :, None], logl, -jnp.inf))
-        cb = jnp.einsum("bihn,bjhn->bijh",
-                        cz.astype(jnp.float32), bz.astype(jnp.float32))
-        w = cb * l * dtz[:, None, :, :]                  # weight on x_j
-        y_intra = jnp.einsum("bijh,bjhp->bihp", w, xz.astype(jnp.float32))
+    def body(state, blk):
+        xk, dtk, bk, ck = blk
+        y, state = _ssd_block(xk, dtk, a, bk, ck, chunk, state)
+        return state, y
 
-        # inter-chunk: y_inter[i] = exp(cum_i) * c_i . state; this and
-        # the state update's exponents are <= 0 (dt >= 0, A < 0)
-        y_inter = jnp.einsum("bihn,bhpn->bihp", cz.astype(jnp.float32), state)
-        y_inter = y_inter * jnp.exp(cum)[..., None]
+    final, ys = jax.lax.scan(body, init, tuple(map(blocks, (x, dt, b, c))))
+    return jnp.moveaxis(ys, 0, 1).reshape(bs, s, h, p), final
 
-        # state update: decay-to-end-weighted outer products
-        dec_to_end = jnp.exp(seg_total[:, None, :] - cum)  # (B,Q,H)
-        s_chunk = jnp.einsum("bjh,bjhn,bjhp->bhpn",
-                             dec_to_end * dtz, bz.astype(jnp.float32),
-                             xz.astype(jnp.float32))
-        new_state = state * jnp.exp(seg_total)[:, :, None, None] + s_chunk
-        return new_state, (y_intra + y_inter).astype(x.dtype)
 
-    final, ys = jax.lax.scan(scan_body, init, (xr, br, cr, dtr))
-    y = ys.transpose(1, 0, 2, 3, 4).reshape(bs, s, h, p)
-    return y, final
+def _segment_decay(cum: jax.Array) -> jax.Array:
+    """L[i, j] = exp(cum_i - cum_j) for j <= i, else 0; (..., Q) to
+    (..., Q, Q).
+
+    Masked before the exp, as the paper's segment sum is: above the
+    diagonal the exponent is positive and overflows fp32 once a chunk's
+    decay passes ~88, and the backward's 0 * inf would be NaN."""
+    q = cum.shape[-1]
+    mask = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
+    return jnp.exp(jnp.where(mask, cum[..., :, None] - cum[..., None, :],
+                             -jnp.inf))
+
+
+def _ssd_block(x, dt, a, b, c, chunk, state):
+    """:func:`ssd_chunked` over every chunk of ``x`` at once, from the
+    fp32 ``state`` (B, H, P, N) and with ``a`` = A (H,)."""
+    bs, s, h, p = x.shape
+    g, n = b.shape[2:]
+    r, nc = h // g, s // chunk
+    z = nc * bs * g
+    f32 = jnp.float32
+    # head-major chunked layout, (c, B, G) flattened to z: x (z, R, Q, P),
+    # the (Q, Q) blocks in the two minor dimensions, B and C per group
+    xr = x.reshape(bs, nc, chunk, g, r, p).transpose(1, 0, 3, 4, 2, 5)
+    xr = xr.reshape(z, r, chunk, p).astype(f32)
+    dtr, dta = (t.reshape(bs, nc, chunk, g, r).transpose(1, 0, 3, 4, 2)
+                .reshape(z, r, chunk) for t in (dt.astype(f32), dt * a))
+    br, cr = (t.reshape(bs, nc, chunk, g, n).transpose(1, 0, 3, 2, 4)
+              .reshape(z, chunk, n).astype(f32) for t in (b, c))
+    cum = jnp.cumsum(dta, axis=-1)                       # (z, R, Q)
+    seg_total = cum[..., -1]                             # (z, R)
+
+    # intra-chunk quadratic form, C·Bᵀ once per group
+    cb = jnp.einsum("zin,zjn->zij", cr, br)
+    w = cb[:, None] * _segment_decay(cum) * dtr[..., None, :]
+    y = jnp.einsum("zrij,zrjp->zrip", w, xr)
+
+    # each chunk's own state at its end: decay-to-end-weighted outer
+    # products; these exponents and exp(cum) are <= 0 (dt >= 0, A < 0)
+    x_end = xr * (jnp.exp(seg_total[..., None] - cum) * dtr)[..., None]
+    s_chunk = jnp.einsum("zrjp,zjn->zrpn", x_end, br)
+
+    # only the (B, G, R, P, N) state crosses chunks, in a scan over them
+    def carry(st, inp):
+        decay, s_c = inp
+        return st * decay[..., None, None] + s_c, st
+
+    final, prev = jax.lax.scan(
+        carry, state.reshape(bs * g, r, p, n),
+        (jnp.exp(seg_total).reshape(nc, bs * g, r),
+         s_chunk.reshape(nc, bs * g, r, p, n)))
+    # inter-chunk: y_inter[i] = exp(cum_i) * c_i . state entering the chunk
+    y_inter = jnp.einsum("zin,zrpn->zrip", cr, prev.reshape(z, r, p, n))
+    y = (y + y_inter * jnp.exp(cum)[..., None]).astype(x.dtype)
+    y = y.reshape(nc, bs, g, r, chunk, p).transpose(1, 0, 4, 2, 3, 5)
+    return y.reshape(bs, s, h, p), final.reshape(bs, h, p, n)
 
 
 def ssd_decode_step(x: jax.Array, dt: jax.Array, a_log: jax.Array,
@@ -202,15 +253,14 @@ def mamba_forward(x: jax.Array, p: dict, cfg: ModelConfig,
     cs = conv_out[..., d_in + s.n_groups * s.d_state :]
 
     xh = xs.reshape(bsz, seq, nh, s.head_dim)
-    bh = _broadcast_groups(bs_, nh, s)
-    ch = _broadcast_groups(cs, nh, s)
+    bg = bs_.reshape(bsz, seq, s.n_groups, s.d_state)
+    cg = cs.reshape(bsz, seq, s.n_groups, s.d_state)
     dt_sp = jax.nn.softplus(dt.astype(jnp.float32)
                             + p["dt_bias"].astype(jnp.float32))
 
-    chunk = min(s.chunk, seq)
     with jax.named_scope("ssd"):
-        y, final = ssd_chunked(xh, dt_sp, p["a_log"], bh, ch, chunk,
-                               state0=state0)
+        y, final = ssd_chunked(xh, dt_sp, p["a_log"], bg, cg,
+                               min(s.chunk, seq), state0=state0)
     y = y + xh * p["d_skip"].astype(jnp.float32)[None, None, :, None].astype(y.dtype)
     y = y.reshape(bsz, seq, d_in)
     # gated RMSNorm (mamba-2): norm(y * silu(z))

@@ -223,8 +223,10 @@ class SpareTrainer:
 
     def _count_compile(self, report: TrainReport | None) -> None:
         """Account one new step program: the run's and the telemetry's
-        recompile counts, and how many attention layers it runs through
-        the flash kernel (``train.attention_kernel_layers``)."""
+        recompile counts, how many attention layers it runs through
+        the flash kernel (``train.attention_kernel_layers``), and how many
+        blocks each SSD call of its micro-batches runs its chunks in
+        (``train.ssd_chunk_blocks``, 0 with no SSM layer)."""
         if report is not None:
             report.recompiles += 1
         tel = self.telemetry
@@ -232,6 +234,10 @@ class SpareTrainer:
             tel.counter("train.recompiles").inc()
             tel.gauge("train.attention_kernel_layers").set(
                 self.model.flash_layers(self.pipeline.seq))
+            tel.gauge("train.ssd_chunk_blocks").set(
+                self.model.ssd_chunk_blocks(
+                    self.state.n * self.pipeline.per_type_batch,
+                    self.pipeline.seq))
 
     def _step_batch(self, state, step: int) -> dict:
         """Step ``step``'s batch under ``state``'s schedule, on device."""

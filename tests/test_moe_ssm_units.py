@@ -11,6 +11,7 @@ except ImportError:                      # pragma: no cover
 
 from repro.models.config import ModelConfig, MoEConfig, SSMConfig
 from repro.models.moe import expert_ffn_local, moe_ffn_reference, route_topk
+from repro.models import ssm
 from repro.models.ssm import ssd_chunked, ssd_decode_step
 
 RNG = np.random.default_rng(0)
@@ -204,52 +205,118 @@ def test_ssd_chunked_backward_finite_at_published_decay(dt_value):
                                    err_msg=f"d{name}")
 
 
-def _ssd_exp_then_mask(x, dt, a_log, b, c, chunk):
-    """``ssd_chunked``'s forward as it read before the log-decay was
-    masked ahead of the exponential (``where(mask, exp(logl), 0)``)."""
-    bs, s, h, p = x.shape
-    n = b.shape[-1]
-    nc = s // chunk
-    a = -jnp.exp(a_log.astype(jnp.float32))
-    xr = x.reshape(bs, nc, chunk, h, p).transpose(1, 0, 2, 3, 4)
-    br = b.reshape(bs, nc, chunk, h, n).transpose(1, 0, 2, 3, 4)
-    cr = c.reshape(bs, nc, chunk, h, n).transpose(1, 0, 2, 3, 4)
-    dtr = dt.reshape(bs, nc, chunk, h).transpose(1, 0, 2, 3)
-    mask = jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :]
-
-    def body(state, inp):
-        xz, bz, cz, dtz = inp
-        cum = jnp.cumsum(dtz * a[None, None, :], axis=1)
-        seg_total = cum[:, -1]
-        logl = cum[:, :, None, :] - cum[:, None, :, :]
-        l = jnp.where(mask[None, :, :, None], jnp.exp(logl), 0.0)
-        cb = jnp.einsum("bihn,bjhn->bijh", cz, bz)
-        w = cb * l * dtz[:, None, :, :]
-        y_intra = jnp.einsum("bijh,bjhp->bihp", w, xz)
-        y_inter = jnp.einsum("bihn,bhpn->bihp", cz, state)
-        y_inter = y_inter * jnp.exp(cum)[..., None]
-        dec_to_end = jnp.exp(seg_total[:, None, :] - cum)
-        s_chunk = jnp.einsum("bjh,bjhn,bjhp->bhpn", dec_to_end * dtz, bz, xz)
-        new_state = state * jnp.exp(seg_total)[:, :, None, None] + s_chunk
-        return new_state, y_intra + y_inter
-
-    final, ys = jax.lax.scan(body, jnp.zeros((bs, h, p, n), jnp.float32),
-                             (xr, br, cr, dtr))
-    return ys.transpose(1, 0, 2, 3, 4).reshape(bs, s, h, p), final
+def _exp_then_mask(cum):
+    """``ssm._segment_decay`` as it read before the log-decay was masked
+    ahead of the exponential (``where(mask, exp(logl), 0)``)."""
+    q = cum.shape[-1]
+    mask = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
+    return jnp.where(mask, jnp.exp(cum[..., :, None] - cum[..., None, :]),
+                     0.0)
 
 
 @pytest.mark.parametrize("dt_value,chunk", [(0.01, 64), (0.05, 256),
                                             (0.1, 256)])
-def test_ssd_chunked_forward_unchanged_by_the_mask(dt_value, chunk):
+def test_ssd_chunked_forward_unchanged_by_the_mask(dt_value, chunk,
+                                                   monkeypatch):
     """exp(-inf) is exactly 0: masking the log-decay first leaves the
     forward bit for bit as the exp-then-mask formula gave it, which is
     finite here."""
     args, _ = _witness(dt_value)
     y, final = ssd_chunked(*args, chunk=chunk)
-    y_old, final_old = _ssd_exp_then_mask(*args, chunk=chunk)
+    monkeypatch.setattr(ssm, "_segment_decay", _exp_then_mask)
+    y_old, final_old = ssd_chunked(*args, chunk=chunk)
     assert bool(jnp.all(jnp.isfinite(y_old)))
     np.testing.assert_array_equal(y, y_old)
     np.testing.assert_array_equal(final, final_old)
+
+
+def _group_inputs(g, s=64, h=4, p=8, n=16):
+    """SSD inputs with B and C per group, (1, s, g, n), and A per head
+    spread over the published init's [1, 16]."""
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(1, s, h, p)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.01, 0.1, (1, s, h)), jnp.float32)
+    a_log = jnp.asarray(np.log(np.linspace(1.0, 16.0, h)), jnp.float32)
+    bb = jnp.asarray(rng.normal(size=(1, s, g, n)), jnp.float32)
+    cc = jnp.asarray(rng.normal(size=(1, s, g, n)), jnp.float32)
+    state0 = jnp.asarray(rng.normal(size=(1, h, p, n)), jnp.float32)
+    ry = jnp.asarray(rng.normal(size=(1, s, h, p)), jnp.float32)
+    rs = jnp.asarray(rng.normal(size=(1, h, p, n)), jnp.float32)
+    return (x, dt, a_log, bb, cc), state0, (ry, rs)
+
+
+def _assert_same(got, want, what):
+    """Equal within fp32 rounding: 1e-5 of the largest element."""
+    for name, g, w in zip(("x", "dt", "a_log", "b", "c"), got, want):
+        np.testing.assert_allclose(
+            g, w, rtol=0, atol=1e-5 * float(jnp.max(jnp.abs(w))),
+            err_msg=f"{what} d{name}")
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_ssd_chunked_group_level_equals_head_broadcast(g):
+    """B and C given per group (G = 1, 2 of H = 4 heads) give the same y,
+    final state and gradients as the same B and C repeated to every head
+    of their group (G = H), from a given state."""
+    args, state0, weights = _group_inputs(g)
+    h = args[0].shape[2]
+
+    @jax.jit
+    def per_head(x, dt, a_log, bb, cc):
+        rep = lambda t: jnp.repeat(t, h // g, axis=2)
+        return ssd_chunked(x, dt, a_log, rep(bb), rep(cc), chunk=16,
+                           state0=state0)
+
+    per_group = jax.jit(lambda *a: ssd_chunked(*a, chunk=16, state0=state0))
+
+    y, final = per_group(*args)
+    y_ref, final_ref = per_head(*args)
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(final, final_ref, rtol=1e-5, atol=1e-6)
+    _assert_same(_grads(per_group, args, weights),
+                 _grads(per_head, args, weights), f"G={g}")
+
+
+@pytest.mark.parametrize("with_state0", [False, True])
+@pytest.mark.parametrize("budget,blocks", [(2 * 4096, 2), (4096, 4)])
+def test_ssd_chunked_blocks_equal_one_block(budget, blocks, with_state0,
+                                            monkeypatch):
+    """A call whose (Q, Q) working set passes the budget runs its four
+    chunks in blocks under a scan, and equals the one-block call forward
+    and in every gradient."""
+    args, state0, weights = _group_inputs(1)
+    kw = {"chunk": 16, "state0": state0 if with_state0 else None}
+    fn = lambda *a: ssd_chunked(*a, **kw)
+    assert ssm.ssd_blocks(1, 64, 4, 16) == 1
+    y, final = fn(*args)
+    grads = _grads(fn, args, weights)
+
+    # one chunk's working set is 1 * 4 * 16 * 16 * 4 = 4096 bytes
+    monkeypatch.setattr(ssm, "QQ_BUDGET_BYTES", budget)
+    assert ssm.ssd_blocks(1, 64, 4, 16) == blocks
+    y_blk, final_blk = fn(*args)
+    np.testing.assert_allclose(y_blk, y, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(final_blk, final, rtol=1e-5, atol=1e-6)
+    _assert_same(_grads(fn, args, weights), grads, f"{blocks} blocks")
+
+
+@pytest.mark.parametrize("arch,budget,blocks", [
+    ("mamba2-1.3b", None, 1), ("mamba2-1.3b", 1, 2), ("qwen2.5-3b", None, 0)])
+def test_ssd_chunk_blocks_gauge(arch, budget, blocks, monkeypatch):
+    """The trainer's step reports how many blocks each SSD call runs its
+    chunks in: 1 on a small Mamba-2 model, as many as its chunks where
+    one chunk passes the budget, 0 on a dense model."""
+    from repro.configs import smoke_config
+    from repro.obs.trace import Telemetry
+    from repro.train.trainer import SpareTrainer
+
+    if budget is not None:
+        monkeypatch.setattr(ssm, "QQ_BUDGET_BYTES", budget)
+    tel = Telemetry(trace=False)
+    tr = SpareTrainer(smoke_config(arch), n_groups=4, redundancy=2, seq=64,
+                      per_type_batch=1, total_steps=100, telemetry=tel)
+    tr.run(1)
+    assert tel.snapshot()["gauges"]["train.ssd_chunk_blocks"] == blocks
 
 
 @pytest.mark.parametrize("arch,dtype", [("mamba2-1.3b", jnp.float32),

@@ -240,7 +240,11 @@ def mamba_cell_step(one_chip):
 
 
 def test_mamba_cell_step_fits_one_v5e(mamba_cell_step):
+    """The cell's step fits 16 GiB, and its SSD scan holds no fp32
+    (B, Q, Q, H) tensor: the (Q, Q) blocks are head-major, and C·Bᵀ is
+    taken once per group, not once per head."""
     assert 0 < _peak_bytes(mamba_cell_step) < V5E_HBM_BYTES
+    assert "f32[4,256,256,64]" not in mamba_cell_step.as_text()
 
 
 def test_mamba_cell_step_scan_is_scoped_on_v5e(mamba_cell_step):
