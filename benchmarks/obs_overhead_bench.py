@@ -1,9 +1,10 @@
 """Telemetry overhead benchmark: steps/s with tracing on vs off.
 
-Runs the real repro.exec mesh training loop (default: 4 DP groups x
-TP 2 on 8 emulated host devices) three ways over interleaved rounds on
-ONE executor — same executable, same prefetch state, same schedule —
-toggling only ``executor.telemetry`` between rounds:
+Runs the real repro.exec mesh training loop (default: 4 DP groups x 2
+model-axis replicas on 8 emulated host devices) three ways over
+interleaved rounds on ONE executor — same executable, same prefetch
+state, same schedule — toggling only ``executor.telemetry`` between
+rounds:
 
 * ``off``     — ``telemetry=None``: the allocation-free null path;
 * ``metrics`` — ``Telemetry(trace=False)``: counters/gauges/histograms
@@ -62,7 +63,6 @@ def main() -> None:
     ap.add_argument("--redundancy", type=int, default=2)
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--per-type-batch", type=int, default=2)
-    ap.add_argument("--sync", default="shard_map")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--max-overhead-pct", type=float, default=None,
                     help="CI gate: fail if full tracing costs more than "
@@ -79,7 +79,7 @@ def main() -> None:
     cfg = smoke_config(args.arch).scaled(grad_accum=1)
     ex = MeshExecutor(cfg, n_groups=args.n_groups,
                       redundancy=args.redundancy,
-                      model_degree=args.model_degree, sync=args.sync,
+                      model_degree=args.model_degree,
                       seq=args.seq, per_type_batch=args.per_type_batch,
                       total_steps=10_000, seed=args.seed)
 
@@ -111,7 +111,7 @@ def main() -> None:
     rec = {
         "bench": "obs_overhead",
         "arch": args.arch,
-        "mesh": f"{args.n_groups}x{args.model_degree}/{args.sync}",
+        "mesh": f"{args.n_groups}x{args.model_degree}",
         "steps_per_round": args.steps,
         "rounds": args.rounds,
         "steps_per_s": {m: round(med[m], 3) for m in modes},   # best-of

@@ -26,7 +26,6 @@ from . import (
     fig6_time_to_train,
     fig7_availability,
     fig8_stacks,
-    kernels_bench,
     rectlr_bench,
     roofline,
     table2_min_ttt,
@@ -42,7 +41,6 @@ SUITES = {
     "table2": table2_min_ttt,
     "tablesC": tables_c_montecarlo,
     "rectlr": rectlr_bench,
-    "kernels": kernels_bench,
     "roofline": roofline,
 }
 

@@ -59,7 +59,7 @@ One chip, in order:
    up to bf16 noise.
 
 Four chips: a ``MeshExecutor`` with 4 groups over the 4 chips
-(``model_degree=1``, ``sync="shard_map"``). It prints which devices hold
+(``model_degree=1``). It prints which devices hold
 each param leaf (all four must), checks the mesh gradient of every
 one-group survivor set against the single-device vanilla-DP oracle
 (``repro.exec.equivalence.survivor_set_sweep``), runs a healthy step and
@@ -270,8 +270,7 @@ def four_chip_phase(seed: int) -> None:
     from repro.train.injection import ScriptedInjector
 
     parse = train_launch.build_parser().parse_args
-    mesh_argv = train_argv(LAYERS, seed, "--mesh", "--model-degree",
-                           "1", "--sync", "shard_map")
+    mesh_argv = train_argv(LAYERS, seed, "--mesh", "--model-degree", "1")
     ex = train_launch.build_trainer(parse(mesh_argv))
     all_ids = sorted(d.id for d in jax.devices())
     for path, leaf in jax.tree_util.tree_leaves_with_path(ex.params):

@@ -16,12 +16,11 @@ from .equivalence import (
     survivor_set_sweep,
     tree_max_rel_err,
 )
-from .executor import MeshExecutor, executor_param_specs
+from .executor import MeshExecutor
 
 __all__ = [
     "MeshExecutor",
     "SurvivorCheck",
-    "executor_param_specs",
     "int8_sweep_tolerance",
     "recoverable_failure_sets",
     "survivor_set_sweep",

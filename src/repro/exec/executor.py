@@ -4,36 +4,25 @@
 the device plane swapped from one-process emulation to a sharded program
 over a ``(data, model)`` mesh (:func:`repro.launch.mesh
 .make_emulated_mesh` / :func:`~repro.launch.mesh.make_production_mesh`).
-Two sync spellings of the same pure ``make_train_step`` are supported:
+The same pure ``make_train_step`` runs as one manual ``shard_map``
+program — the §3.1 wire protocol made explicit: one SPARe DP group per
+``data`` slice, supplier-weighted local gradients reduced ONCE per step
+via ``weighted_all_reduce(..., axis_name="data")`` + a **bucketed flat
+gradient sync** (:class:`~repro.dist.collectives.BucketedAllReduce`):
+the gradient pytree is flattened into a handful of size-capped
+contiguous fp32 buckets, so the per-step sync costs O(1) collectives
+regardless of leaf count, with a bit-transparent unflatten. Per-device
+parameters are replicas (pure DP): the ``model`` axis holds copies of
+the step, and the program carries no tensor-parallel collectives.
 
-* ``sync="shard_map"`` (default) — the §3.1 wire protocol made explicit:
-  manual ``shard_map`` over the mesh, one SPARe DP group per ``data``
-  slice, supplier-weighted local gradients reduced ONCE per step via
-  ``weighted_all_reduce(..., axis_name="data")`` + a **bucketed flat
-  gradient sync** (:class:`~repro.dist.collectives.BucketedAllReduce`):
-  the gradient pytree is flattened into a handful of size-capped
-  contiguous fp32 buckets, so the per-step sync costs O(1) collectives
-  regardless of leaf count, with a bit-transparent unflatten. Per-device
-  parameters are replicas (pure DP), which keeps the manual program
-  free of tensor-parallel collectives.
-* ``sync="gspmd"`` — the dry-run's production spelling: ``jit`` with
-  NamedShardings, parameters/Adam moments sharded on ``model``, the
-  stacked batch on ``data``; GSPMD derives the identical all-reduce
-  from the batch-sharded weighted contraction. (The mixed
-  manual-data/auto-model ``shard_map`` would unify the two, but XLA's
-  partial-manual subgroup handling hard-crashes on scanned+remat
-  programs in the pinned toolchain — ``IsManualSubgroup`` check — so
-  the executor keeps the two proven paths instead.)
-
-``grad_compress="int8_ef"`` (shard_map sync only) swaps the bucketed
-psum for the two-phase int8 error-feedback wire protocol
-(:class:`~repro.dist.collectives.CompressedBucketSync`): int8 payloads +
-per-bucket fp32 scales over the wire (~4x fewer gradient-sync bytes,
-gated on compiled HLO by ``launch/hlo.py``), dequant-accumulated in fp32
-inside the ``shard_map`` program — never int-psummed, so no overflow at
-any DP degree. The EF residuals are device-local sharded state threaded
-through the step (donated like params/opt) and preserved across
-wipe-out rollback.
+``grad_compress="int8_ef"`` swaps the bucketed psum for the two-phase
+int8 error-feedback wire protocol (:class:`~repro.dist.collectives
+.CompressedBucketSync`): int8 payloads + per-bucket fp32 scales over the
+wire (~4x fewer gradient-sync bytes, gated on compiled HLO by
+``launch/hlo.py``), dequant-accumulated in fp32 inside the ``shard_map``
+program — never int-psummed, so no overflow at any DP degree. The EF
+residuals are device-local sharded state threaded through the step
+(donated like params/opt) and preserved across wipe-out rollback.
 
 Input feeding is **per-host**: each batch leaf is built with
 ``jax.make_array_from_callback``, so a host materializes only the
@@ -41,13 +30,13 @@ example rows its addressable shards cover (the pipeline is counter-based
 and coordination-free), and the next step's rows are prefetched on a
 builder thread while the dispatched step executes (double buffering).
 
-Failure masking is identical in all modes: recovery is pure weight-table
-data. After ``scheme.recover`` re-plans the schedule, the next step
-feeds the new ``SpareState.device_schedule()`` weights through the
-batch — no resharding, no new collectives, no recompile (executables
-are cached per ``S_A``). The paper's zero-extra-collectives property is
-asserted on compiled HLO in ``tests/test_exec.py`` — with and without
-compression — and the whole :class:`~repro.train.injection
+Failure masking is identical with and without compression: recovery is
+pure weight-table data. After ``scheme.recover`` re-plans the schedule,
+the next step feeds the new ``SpareState.device_schedule()`` weights
+through the batch — no resharding, no new collectives, no recompile
+(executables are cached per ``S_A``). The paper's zero-extra-collectives
+property is asserted on compiled HLO in ``tests/test_exec.py`` — with
+and without compression — and the whole :class:`~repro.train.injection
 .ScenarioInjector` bridge is inherited, so rack/pod burst events from
 the scenario engine re-weight the live mesh step mid-run.
 
@@ -77,25 +66,9 @@ from repro.train.step import make_train_step, weighted_loss
 from repro.train.trainer import SpareTrainer, TrainReport
 
 
-__all__ = ["MeshExecutor", "executor_param_specs"]
+__all__ = ["MeshExecutor"]
 
-_SYNCS = ("shard_map", "gspmd")
 _COMPRESS = (None, "int8_ef")
-
-
-def executor_param_specs(params, model_degree: int):
-    """Model-axis specs for the gspmd layout: every matrix whose last dim
-    divides the TP degree is column-sharded on ``model``; everything else
-    (norm scales, ragged leaves) is replicated. All leaves are replicated
-    across ``data`` — that axis carries the stacked batch and its
-    all-reduced gradients, exactly vanilla DP + SPARe weights."""
-
-    def spec(leaf):
-        if leaf.ndim >= 2 and leaf.shape[-1] % model_degree == 0:
-            return P(*(None,) * (leaf.ndim - 1), "model")
-        return P()
-
-    return jax.tree.map(spec, params)
 
 
 class MeshExecutor(SpareTrainer):
@@ -106,14 +79,13 @@ class MeshExecutor(SpareTrainer):
     mesh: a ``(data, model)`` mesh to run on; by default an emulated one
         with ``data == n_groups`` slices (requires
         ``n_groups * model_degree`` visible devices).
-    model_degree: tensor-parallel degree of the default mesh (gspmd
-        sync; the manual shard_map program treats model columns as
-        replicas).
-    sync: ``"shard_map"`` (explicit bucketed psum) or ``"gspmd"``
-        (NamedShardings, params on the model axis) — see the module
-        docstring.
+    model_degree: size of the default mesh's ``model`` axis, along
+        which the manual program replicates the step.
+    sync: only ``"shard_map"``, the one spelling of the gradient sync;
+        any other value raises ``ValueError``. A check, not a knob: it
+        stays while ``bench/tests/bench_tiny.py`` still passes it.
     grad_compress: ``None`` (fp32 buckets on the wire) or ``"int8_ef"``
-        (two-phase int8 error-feedback compressed sync; shard_map only).
+        (two-phase int8 error-feedback compressed sync).
     bucket_mb: flat-bucket size cap in MiB of fp32 — the gradient sync
         issues O(total_params / bucket) collectives per step, never one
         per leaf.
@@ -125,22 +97,18 @@ class MeshExecutor(SpareTrainer):
                  grad_compress: str | None = None, bucket_mb: float = 32.0,
                  base_lr: float = 3e-4, total_steps: int = 1000,
                  **kwargs: Any):
-        if sync not in _SYNCS:
-            raise ValueError(f"sync must be one of {_SYNCS}, got {sync!r}")
+        if sync != "shard_map":
+            raise ValueError(f"sync must be 'shard_map', the one spelling "
+                             f"of the gradient sync, got {sync!r}")
         if grad_compress not in _COMPRESS:
             raise ValueError(f"grad_compress must be one of {_COMPRESS}, "
                              f"got {grad_compress!r}")
-        if grad_compress and sync != "shard_map":
-            raise ValueError(
-                "grad_compress needs the manual collective program: use "
-                "sync='shard_map' (gspmd derives its own fp32 all-reduce)")
         if mesh is None:
             mesh = make_emulated_mesh(n_groups, model_degree)
         if "model" not in mesh.axis_names or "data" not in mesh.axis_names:
             raise ValueError(f"mesh must carry (data, model) axes, "
                              f"got {mesh.axis_names}")
         self.mesh = mesh
-        self.sync = sync
         self.grad_compress = grad_compress
         self.data_degree = mesh.shape["data"]
         self.model_degree = mesh.shape["model"]
@@ -160,21 +128,19 @@ class MeshExecutor(SpareTrainer):
         # kept across elastic reshapes: any shrunken data axis that
         # divides the original degree still tiles every bucket, so EF
         # residuals move between meshes bit-transparently (repro.elastic)
-        self._grad_sync = None
         self._ef_state = None
         self._ef_snapshot = None
-        self._layout = None
-        # the step is split over the mesh here, not by the model: keep
-        # Pallas attention (which GSPMD cannot partition) off its path
+        # the step is split over the mesh here, not by the model: the
+        # mesh path keeps the pure-jnp attention until a four-chip cell
+        # times the kernel inside the manual program
         self.model = dataclasses.replace(self.model, partitioned=True)
-        if sync == "shard_map":
-            acc = jnp.dtype(cfg.grad_accum_dtype)
-            gtree = jax.tree.map(
-                lambda p: jax.ShapeDtypeStruct(p.shape, acc), self.params)
-            self._layout = bucket_layout(
-                gtree, max_bucket_elems=max(int(bucket_mb * (1 << 20) // 4),
-                                            self.data_degree),
-                pad_to=CompressedBucketSync.LANES * self.data_degree)
+        acc = jnp.dtype(cfg.grad_accum_dtype)
+        gtree = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape, acc), self.params)
+        self._layout = bucket_layout(
+            gtree, max_bucket_elems=max(int(bucket_mb * (1 << 20) // 4),
+                                        self.data_degree),
+            pad_to=CompressedBucketSync.LANES * self.data_degree)
         self._bind_mesh(mesh)
         self.params = jax.device_put(self.params, self._pshard)
         self.opt_state = jax.device_put(self.opt_state, self._oshard)
@@ -201,29 +167,23 @@ class MeshExecutor(SpareTrainer):
         self.mesh = mesh
         self.data_degree = mesh.shape["data"]
         self.model_degree = mesh.shape["model"]
-        if self.sync == "shard_map":
-            if self.grad_compress == "int8_ef":
-                self._grad_sync = CompressedBucketSync(
-                    self._layout, self.data_degree, "data")
-                if self.telemetry is not None and self.telemetry.deep:
-                    # deep mode: in-jit per-bucket markers (changes the
-                    # compiled program)
-                    self._grad_sync.tel = self.telemetry
-            else:
-                self._grad_sync = BucketedAllReduce(self._layout, "data")
+        if self.grad_compress == "int8_ef":
+            self._grad_sync = CompressedBucketSync(
+                self._layout, self.data_degree, "data")
+            if self.telemetry is not None and self.telemetry.deep:
+                # deep mode: in-jit per-bucket markers (changes the
+                # compiled program)
+                self._grad_sync.tel = self.telemetry
+        else:
+            self._grad_sync = BucketedAllReduce(self._layout, "data")
         # the sharded spelling of the step the parent already built: the
-        # same pure function, with the named-axis gradient sync when the
-        # program is manual
+        # same pure function, with the named-axis gradient sync
         self._step_fn = make_train_step(
             self.model, base_lr=self._base_lr, total_steps=self.total_steps,
-            axis_name="data" if self.sync == "shard_map" else None,
-            grad_sync=self._grad_sync)
-        if self.sync == "gspmd":
-            p_specs = executor_param_specs(self.params, self.model_degree)
-        else:   # manual program: per-device replicas, pure DP
-            p_specs = jax.tree.map(lambda _: P(), self.params)
+            axis_name="data", grad_sync=self._grad_sync)
+        # per-device replicas, pure DP
         self._pshard = jax.tree.map(
-            lambda s: NamedSharding(mesh, s), p_specs)
+            lambda _: NamedSharding(mesh, P()), self.params)
         self._oshard = type(self.opt_state)(
             step=NamedSharding(mesh, P()),
             mu=jax.tree.map(lambda s: s, self._pshard),
@@ -253,21 +213,17 @@ class MeshExecutor(SpareTrainer):
         return specs
 
     def _wrap_step(self, fn):
-        """The jit-able sharded step for the configured sync mode."""
-        if self.sync == "shard_map":
-            in_specs = [P(), P(), self._batch_specs()]
-            out_specs = [P(), P(), P()]
-            if self.grad_compress:
-                ef = self._grad_sync.state_specs()
-                in_specs.append(ef)
-                out_specs.append(ef)
-            # replication checking off: the replicated out_specs rest on
-            # psum_partial's custom VJP, which the checker cannot see
-            return jax.shard_map(fn, mesh=self.mesh,
-                                 in_specs=tuple(in_specs),
-                                 out_specs=tuple(out_specs),
-                                 check_vma=False)
-        return fn   # gspmd: sharding comes from jit in/out shardings
+        """The jit-able sharded step: ``fn`` as a manual program."""
+        in_specs = [P(), P(), self._batch_specs()]
+        out_specs = [P(), P(), P()]
+        if self.grad_compress:
+            ef = self._grad_sync.state_specs()
+            in_specs.append(ef)
+            out_specs.append(ef)
+        # replication checking off: the replicated out_specs rest on
+        # psum_partial's custom VJP, which the checker cannot see
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=tuple(in_specs),
+                             out_specs=tuple(out_specs), check_vma=False)
 
     def _cache_key(self, s_a: int) -> tuple[int, int, int]:
         """Executable-cache key: ``(data_degree, model_degree, s_a)``.
@@ -286,11 +242,8 @@ class MeshExecutor(SpareTrainer):
         # donated buffer (repro.analysis donation-audit pass).
         key = self._cache_key(s_a)
         if key not in self._jitted:
-            out_shardings = ((self._pshard, self._oshard, None)
-                             if self.sync == "gspmd" else None)
             donate = (0, 1, 3) if self.grad_compress else (0, 1)
             self._jitted[key] = jax.jit(self._wrap_step(self._step_fn),
-                                        out_shardings=out_shardings,
                                         donate_argnums=donate)
             # total_recompiles is the order-independent count (HLO
             # inspection can warm the cache outside any run); a run's
@@ -495,33 +448,26 @@ class MeshExecutor(SpareTrainer):
         ``exec/equivalence.py::int8_sweep_tolerance``)."""
         if self._mesh_grad_fn is None:
             model = self.model
-            axis = "data" if self.sync == "shard_map" else None
             sync = self._grad_sync
 
             def total_loss(params, batch):
                 def body(acc, micro):
                     return acc + weighted_loss(model, params, micro,
-                                               axis_name=axis), None
+                                               axis_name="data"), None
                 out, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
                                       batch)
                 return out
 
             def grads(params, batch):
                 g = jax.grad(total_loss)(params, batch)
-                if axis is None:
-                    return g
                 if self.grad_compress:
                     return sync.sync_once(g)
                 return sync(g)
 
-            if self.sync == "shard_map":
-                fn = jax.shard_map(grads, mesh=self.mesh,
-                                   in_specs=(P(), self._batch_specs()),
-                                   out_specs=P(), check_vma=False)
-                self._mesh_grad_fn = jax.jit(fn)
-            else:
-                self._mesh_grad_fn = jax.jit(
-                    grads, out_shardings=self._pshard)
+            fn = jax.shard_map(grads, mesh=self.mesh,
+                               in_specs=(P(), self._batch_specs()),
+                               out_specs=P(), check_vma=False)
+            self._mesh_grad_fn = jax.jit(fn)
         batch = self._device_batch(step, state)
         return self._mesh_grad_fn(self.params, batch)
 

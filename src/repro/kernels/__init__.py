@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels: int8_ef.py (the compressed gradient sync's fused
+# quantize-accumulate), its jitted wrapper in ops.py and its jnp oracle
+# in ref.py. A kernel lands here only with a caller on a production path.

@@ -1,62 +1,11 @@
-"""Pure-jnp oracles for every Pallas kernel (the ``assert_allclose``
-ground truth; deliberately naive and readable)."""
+"""Pure-jnp oracle for the Pallas kernel (the ``assert_allclose`` ground
+truth; deliberately naive and readable)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention_ref", "ssd_scan_ref", "rmsnorm_ref",
-           "int8_ef_ref"]
-
-
-def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                        causal: bool = True) -> jax.Array:
-    """Naive full-matrix attention with GQA head grouping.
-
-    q: (B, H, S, D); k, v: (B, KV, S, D) with H % KV == 0.
-    fp32 softmax; returns (B, H, S, D) in q.dtype.
-    """
-    b, h, s, d = q.shape
-    kv = k.shape[1]
-    rep = h // kv
-    k = jnp.repeat(k, rep, axis=1)
-    v = jnp.repeat(v, rep, axis=1)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
-    scores = scores * (d ** -0.5)
-    if causal:
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(mask[None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v)
-
-
-def ssd_scan_ref(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-                 c: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Token-by-token SSD recurrence (the definition, not the chunked
-    algorithm): S_t = exp(dt_t a) S_{t-1} + dt_t b_t (x) x_t; y_t = c_t.S_t.
-
-    x: (B, H, S, P); dt: (B, H, S); a: (H,); b, c: (B, H, S, N).
-    Returns (y (B,H,S,P), final state (B,H,P,N)), fp32 math.
-    """
-    bs, h, s, p = x.shape
-    n = b.shape[-1]
-    xf = x.astype(jnp.float32)
-    dtf = dt.astype(jnp.float32)
-    bf = b.astype(jnp.float32)
-    cf = c.astype(jnp.float32)
-    af = a.astype(jnp.float32)
-
-    def step(state, t):
-        decay = jnp.exp(dtf[:, :, t] * af[None, :])              # (B,H)
-        outer = jnp.einsum("bhp,bhn->bhpn", xf[:, :, t], bf[:, :, t])
-        state = state * decay[..., None, None] + outer * dtf[:, :, t][..., None, None]
-        y = jnp.einsum("bhpn,bhn->bhp", state, cf[:, :, t])
-        return state, y
-
-    state0 = jnp.zeros((bs, h, p, n), jnp.float32)
-    final, ys = jax.lax.scan(step, state0, jnp.arange(s))
-    y = ys.transpose(1, 2, 0, 3)                                  # (B,H,S,P)
-    return y.astype(x.dtype), final
+__all__ = ["int8_ef_ref"]
 
 
 def int8_ef_ref(grad: jax.Array, error: jax.Array
@@ -71,9 +20,3 @@ def int8_ef_ref(grad: jax.Array, error: jax.Array
     safe = jnp.where(scale > 0, scale, 1.0)
     q = jnp.clip(jnp.round(x / safe), -127.0, 127.0).astype(jnp.int8)
     return q, scale, x - q.astype(jnp.float32) * scale
-
-
-def rmsnorm_ref(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * scale * w.astype(jnp.float32)).astype(x.dtype)

@@ -121,14 +121,13 @@ def certify_executors() -> Report:
     report = Report()
     cfg = smoke_config("qwen2.5-3b").scaled(grad_accum=1)
 
-    # every sync variant of the production step, swept over the FULL
+    # both wire formats of the production step's gradient sync (fp32
+    # buckets, int8 EF), each swept over the FULL
     # RECTLR-recoverable survivor space (n=4, r=2: all singles + the
     # doubles the controller can mask)
-    variants = [("shard_map", None), ("gspmd", None),
-                ("shard_map", "int8_ef")]
-    for sync, compress in variants:
-        tag = f"executor:{sync}" + (f"+{compress}" if compress else "")
-        ex = MeshExecutor(cfg, sync=sync, grad_compress=compress,
+    for compress in (None, "int8_ef"):
+        tag = "executor:shard_map" + (f"+{compress}" if compress else "")
+        ex = MeshExecutor(cfg, grad_compress=compress,
                           n_groups=4, redundancy=2, model_degree=2,
                           seq=32, per_type_batch=2, total_steps=50)
         text = ex.compiled_step_text()
@@ -152,8 +151,7 @@ def certify_executors() -> Report:
     for compress in (None, "int8_ef"):
         tag = "executor:elastic-reshaped" + (f"+{compress}" if compress
                                              else "")
-        elx = ElasticMeshExecutor(cfg, sync="shard_map",
-                                  grad_compress=compress, n_groups=8,
+        elx = ElasticMeshExecutor(cfg, grad_compress=compress, n_groups=8,
                                   redundancy=2, model_degree=1,
                                   seq=32, per_type_batch=2, total_steps=50)
         elx.reshape([0, 1])
@@ -182,7 +180,7 @@ def certify_executors() -> Report:
     from repro.train.trainer import TrainReport as _TrainReport
 
     tag = "executor:demoted"
-    dex = MeshExecutor(cfg, sync="shard_map", n_groups=4, redundancy=2,
+    dex = MeshExecutor(cfg, n_groups=4, redundancy=2,
                        model_degree=2, seq=32, per_type_batch=2,
                        total_steps=50)
     factors = np.ones(4)

@@ -134,19 +134,14 @@ def build_parser() -> argparse.ArgumentParser:
                          "under JAX_PLATFORMS=cpu the host platform is "
                          "fanned out into enough emulated devices")
     ap.add_argument("--model-degree", type=int, default=1,
-                    help="tensor-parallel degree of the --mesh mesh")
-    ap.add_argument("--sync", default="shard_map",
-                    choices=("shard_map", "gspmd"),
-                    help="--mesh gradient-sync spelling: explicit "
-                         "bucketed psum under shard_map, or GSPMD "
-                         "NamedShardings with params sharded on the "
-                         "model axis")
+                    help="size of the --mesh mesh's model axis, along "
+                         "which the manual program replicates the step")
     ap.add_argument("--grad-compress", default="none",
                     choices=("none", "int8_ef"),
                     help="--mesh only: compress the bucketed gradient "
                          "sync (int8 payload + per-bucket scales over "
-                         "the wire, EF residuals as device-local state; "
-                         "requires --sync shard_map)")
+                         "the wire, EF residuals as device-local "
+                         "state)")
     ap.add_argument("--elastic", action="store_true",
                     help="with --mesh: enable the elastic recovery tier "
                          "(repro.elastic.ElasticMeshExecutor) — an "
@@ -187,7 +182,7 @@ def build_trainer(args, telemetry=None):
     cfg = resolve_config(args).scaled(grad_accum=1)
     r = _resolve_r(args)
     tag = "" if args.grad_compress == "none" else f"+{args.grad_compress}"
-    plane = (f"{args.n_groups}x{args.model_degree}/{args.sync}{tag}"
+    plane = (f"{args.n_groups}x{args.model_degree}{tag}"
              if args.mesh else "emulated")
     print(f"[train] arch={args.arch} N={args.n_groups} r={r} "
           f"scheme={args.scheme} steps={args.steps} mesh={plane} "
@@ -203,7 +198,7 @@ def build_trainer(args, telemetry=None):
     if args.mesh:
         compress = None if args.grad_compress == "none" else \
             args.grad_compress
-        mesh_kw = dict(model_degree=args.model_degree, sync=args.sync,
+        mesh_kw = dict(model_degree=args.model_degree,
                        grad_compress=compress, **common)
         if args.elastic:
             from repro.elastic import ElasticMeshExecutor

@@ -96,7 +96,9 @@ def flash_blocks(s: int, head_dim: int,
     """Block sizes of :func:`attend_flash` for a causal self-attention
     over ``s`` positions, or None where :func:`gqa_forward` keeps
     :func:`attend_chunked`: off the TPU; where the step is ``partitioned``
-    over devices (a Mosaic call cannot be split by GSPMD); at a head size
+    over devices (GSPMD cannot split a Mosaic call, and the mesh
+    executor's manual program keeps jnp until a four-chip cell times the
+    kernel there); at a head size
     off the 128-lane tile; at a length the block does not divide.
     Every block is ``min(s, FLASH_BLOCK)``.
     """

@@ -11,8 +11,8 @@ Projections are split per component (z/x/B/C/dt) instead of one fused
 in_proj so each weight shards cleanly over the ``model`` axis (heads and
 d_inner are model-sharded; the small B/C/dt projections replicate).
 
-The per-chunk quadratic form is the Pallas kernel target
-(``repro/kernels/ssd_scan.py``); :func:`ssd_chunked` doubles as its oracle.
+The per-chunk quadratic form runs as plain jnp; a Pallas kernel for it
+would be checked against :func:`ssd_chunked`.
 """
 from __future__ import annotations
 

@@ -3,8 +3,8 @@
 Registers two environment markers and auto-skips them when their
 backend is absent — known environment gaps, not regressions:
 
-* ``tpu`` — the Pallas kernel bodies and the lowered-HLO comparisons
-  need the real TPU toolchain (Mosaic). Run them on a TPU VM with
+* ``tpu`` — lowered-HLO comparisons that hold only as the real TPU
+  toolchain lowers the program. Run them on a TPU VM with
   ``pytest -m tpu`` (they un-skip once ``jax.devices("tpu")``
   resolves).
 * ``spmd`` — the ``repro.exec`` mesh tests need >= 8 devices. On any

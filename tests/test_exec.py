@@ -4,7 +4,7 @@ All tests are ``spmd``-marked: they need >= 8 devices
 (``XLA_FLAGS=--xla_force_host_platform_device_count=8``; see
 tests/conftest.py). What they prove, per the ISSUE-4 acceptance points:
 
-* the ``shard_map`` / gspmd mesh step's gradients match the host-side
+* the ``shard_map`` mesh step's gradients match the host-side
   emulated trainer within fp32-reduction tolerance — for the healthy
   schedule and for EVERY recoverable survivor set;
 * failure masking is pure weight-table data: a rack burst re-weights
@@ -39,7 +39,7 @@ def host_trainer(cfg):
                         per_type_batch=2, total_steps=50)
 
 
-def _executor(cfg, sync, **kw):
+def _executor(cfg, **kw):
     from repro.exec import MeshExecutor
     kw.setdefault("n_groups", 4)
     kw.setdefault("redundancy", 2)
@@ -47,49 +47,43 @@ def _executor(cfg, sync, **kw):
     kw.setdefault("seq", 32)
     kw.setdefault("per_type_batch", 2)
     kw.setdefault("total_steps", 50)
-    return MeshExecutor(cfg, sync=sync, **kw)
+    return MeshExecutor(cfg, **kw)
 
 
 @pytest.fixture(scope="module")
-def executors(cfg):
-    return {sync: _executor(cfg, sync) for sync in ("shard_map", "gspmd")}
+def executor(cfg):
+    return _executor(cfg)
 
 
 @pytest.fixture(scope="module")
 def compressed(cfg):
-    return _executor(cfg, "shard_map", grad_compress="int8_ef")
+    return _executor(cfg, grad_compress="int8_ef")
 
 
 # ------------------------------------------------------------------ #
 # mesh-vs-host §3.1 equivalence                                      #
 # ------------------------------------------------------------------ #
-@pytest.mark.parametrize("sync", ["shard_map", "gspmd"])
-def test_mesh_matches_host_healthy(executors, host_trainer, sync):
+def test_mesh_matches_host_healthy(executor, host_trainer):
     from repro.exec import tree_max_rel_err
-    ex = executors[sync]
-    mesh = ex.mesh_grads(0)
+    mesh = executor.mesh_grads(0)
     host = host_trainer.spare_grads(0)
     assert tree_max_rel_err(mesh, host) < TOL
 
 
-@pytest.mark.parametrize("sync", ["shard_map", "gspmd"])
-def test_params_placed_as_declared(executors, sync):
-    ex = executors[sync]
-    embed = ex.params["embed"]
+def test_params_placed_as_declared(executor):
+    embed = executor.params["embed"]
     assert embed.sharding.mesh.shape == {"data": 4, "model": 2}
     spec = tuple(embed.sharding.spec)
-    if sync == "gspmd":   # vocab table column-sharded on the model axis
-        assert spec[-1] == "model"
-    else:                 # manual program: per-device replicas
-        assert all(s is None for s in spec) or spec == ()
+    # manual program: per-device replicas
+    assert all(s is None for s in spec) or spec == ()
 
 
-def test_survivor_set_enumeration_matches_host(executors, host_trainer):
+def test_survivor_set_enumeration_matches_host(executor, host_trainer):
     """The full §3.1 sweep: every recoverable failure set's mesh gradient
     equals both the host gradient under the same schedule and the
     vanilla-DP oracle."""
     from repro.exec import survivor_set_sweep
-    checks = survivor_set_sweep(executors["shard_map"], host_trainer)
+    checks = survivor_set_sweep(executor, host_trainer)
     assert checks, "n=4, r=2 must have recoverable failure sets"
     # n=4, r=2 (cyclic Golomb): all 4 singles recover; doubles survive
     # only when no type loses both hosts
@@ -103,15 +97,14 @@ def test_survivor_set_enumeration_matches_host(executors, host_trainer):
 # ------------------------------------------------------------------ #
 # zero extra collectives + no recompile on re-weight                 #
 # ------------------------------------------------------------------ #
-@pytest.mark.parametrize("sync", ["shard_map", "gspmd"])
-def test_masked_step_has_identical_collectives(cfg, executors, sync):
+def test_masked_step_has_identical_collectives(cfg, executor):
     """Masking a failure changes the weight *data*, never the program:
     compiled HLO of the masked step carries exactly the collectives of
     the unmasked step at the same S_A."""
     from repro.core import Rectlr, SpareState
     from repro.launch.hlo import collective_report
 
-    ex = executors[sync]
+    ex = executor
     masked = SpareState(4, 2)
     outcome = Rectlr().on_failures(masked, [0])
     assert not outcome.wipeout
@@ -133,7 +126,7 @@ def test_failure_reweights_live_run_without_recompile(cfg):
 
     # n=8 groups on an (8, 1) mesh: r=3 needs the wider Golomb ruler,
     # and racks of 2 groups make every burst a genuine multi-group kill
-    ex = _executor(cfg, "shard_map", n_groups=8, redundancy=3,
+    ex = _executor(cfg, n_groups=8, redundancy=3,
                    model_degree=1, per_type_batch=1)
     topo = ClusterTopology(n_groups=8, hosts_per_group=2,
                            hosts_per_rack=4)   # 2 DP groups per rack
@@ -201,14 +194,14 @@ def test_dryrun_production_shardings_compile(cfg):
     assert counts.get("all-gather", 0) >= 1
 
 
-def test_per_host_feeding_matches_global_batch(executors):
+def test_per_host_feeding_matches_global_batch(executor):
     """``jax.make_array_from_callback`` feeding (each host materializes
     only its addressable rows, double-buffered) assembles byte-for-byte
     the global stacked batch — healthy and masked schedules alike."""
     from repro.core import Rectlr, SpareState
     from repro.data import spare_batch
 
-    ex = executors["shard_map"]
+    ex = executor
     masked = SpareState(4, 2)
     Rectlr().on_failures(masked, [2])
     for state in (ex.state, masked):
@@ -228,8 +221,8 @@ def test_bucketed_sync_collectives_independent_of_leaf_count(cfg):
 
     from repro.launch.hlo import collective_report
 
-    big = _executor(cfg, "shard_map")                  # one bucket
-    small = _executor(cfg, "shard_map", bucket_mb=0.125)   # ~32k elems/bkt
+    big = _executor(cfg)                    # one bucket
+    small = _executor(cfg, bucket_mb=0.125)   # ~32k elems/bkt
     assert big._layout.n_buckets == 1
     assert small._layout.n_buckets > 1
     n_leaves = len(jax.tree.leaves(big.params))
@@ -250,11 +243,6 @@ def test_bucketed_sync_collectives_independent_of_leaf_count(cfg):
 # ------------------------------------------------------------------ #
 # compressed sync (grad_compress="int8_ef")                          #
 # ------------------------------------------------------------------ #
-def test_compressed_rejected_under_gspmd(cfg):
-    with pytest.raises(ValueError, match="shard_map"):
-        _executor(cfg, "gspmd", grad_compress="int8_ef")
-
-
 def test_compressed_mesh_matches_host_within_quantization(
         compressed, host_trainer):
     from repro.exec import int8_sweep_tolerance, tree_max_rel_err
@@ -276,7 +264,7 @@ def test_compressed_survivor_set_sweep(compressed, host_trainer):
     assert not bad, f"survivor sets violating §3.1 under int8-EF: {bad}"
 
 
-def test_compressed_masked_step_schedule_and_wire_ratio(cfg, executors,
+def test_compressed_masked_step_schedule_and_wire_ratio(cfg, executor,
                                                         compressed):
     """The two ISSUE-5 HLO gates at once: (a) masked and unmasked
     compressed steps carry the identical collective schedule (masking
@@ -303,7 +291,7 @@ def test_compressed_masked_step_schedule_and_wire_ratio(cfg, executors,
     assert int8_bytes > 0.5 * rep["total_bytes"], \
         f"int8 payload should dominate the wire: {rep['by_dtype']}"
 
-    t_base = executors["shard_map"].compiled_step_text(state=healthy)
+    t_base = executor.compiled_step_text(state=healthy)
     ratio = wire_byte_ratio(t_healthy, t_base)
     assert ratio <= 0.3, \
         f"compressed sync wire bytes {ratio:.3f}x of fp32 (> 0.3x)"
@@ -316,7 +304,7 @@ def test_compressed_run_recompiles_only_on_depth_and_keeps_ef(cfg):
     never recompile at constant S_A."""
     import jax
 
-    ex = _executor(cfg, "shard_map", grad_compress="int8_ef")
+    ex = _executor(cfg, grad_compress="int8_ef")
     rep = ex.run(3)
     assert all(np.isfinite(rep.losses))
     assert rep.recompiles == 1
@@ -358,7 +346,7 @@ def test_compressed_run_recompiles_only_on_depth_and_keeps_ef(cfg):
 def test_wipeout_rolls_back_resharded_params(cfg):
     """A wipe-out mid-run restores snapshot params/opt with the mesh
     shardings intact and keeps training."""
-    ex = _executor(cfg, "shard_map", n_groups=4, redundancy=2)
+    ex = _executor(cfg, n_groups=4, redundancy=2)
 
     fired = []
 

@@ -42,6 +42,17 @@ def test_emulated_mesh_too_large_names_the_fix():
         make_emulated_mesh(jax.device_count() + 1, 2)
 
 
+def test_executor_refuses_another_sync_before_building_a_mesh():
+    """``MeshExecutor`` has one gradient-sync spelling; naming another
+    fails at once, before a mesh is asked for (so on one device too)."""
+    from repro.configs import smoke_config
+    from repro.exec import MeshExecutor
+
+    with pytest.raises(ValueError, match="sync must be 'shard_map'"):
+        MeshExecutor(smoke_config("qwen2.5-3b"), sync="named_sharding",
+                     n_groups=4, redundancy=2)
+
+
 def test_production_mesh_geometry_subprocess():
     """Real ``make_production_mesh`` construction at 512 forced host
     devices: shapes, axis names, and DP degrees of both launch targets.

@@ -313,7 +313,7 @@ def test_masked_vs_unmasked_wire_metrics_parity(cfg):
     tel = Telemetry(trace=False)
     ex = MeshExecutor(cfg, n_groups=4, redundancy=2, model_degree=2,
                       seq=32, per_type_batch=2, total_steps=50,
-                      sync="shard_map", telemetry=tel)
+                      telemetry=tel)
     masked = SpareState(4, 2)
     Rectlr().on_failures(masked, [0])
     healthy = SpareState(4, 2)
