@@ -233,6 +233,11 @@ class MeshExecutor(SpareTrainer):
         cache entry per (shape, depth) it visits."""
         return (self.data_degree, self.model_degree, s_a)
 
+    def _micro_rows(self) -> int:
+        """Sequences of a micro-batch on one device: ``shard_map`` hands
+        each its slice of the example axis."""
+        return super()._micro_rows() // self.data_degree
+
     def _compiled(self, s_a: int, report: TrainReport | None = None):
         # Donation contract (analyzer-enforced): params, opt_state, and —
         # under int8_ef — the EF residuals are donated, and every donated

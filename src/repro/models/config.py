@@ -94,7 +94,9 @@ class ModelConfig:
     # training defaults
     grad_accum: int = 4            # paper Table 1: 4 gradient accumulations
     remat: bool = True
-    remat_policy: str = "nothing"  # nothing | dots | none (no remat)
+    # auto | nothing | dots | none (no remat); auto keeps the matmul
+    # outputs where they fit the device's memory (Model.remat_for)
+    remat_policy: str = "auto"
     moment_dtype: str = "float32"      # Adam m/v ("bfloat16" at 671B scale)
     grad_accum_dtype: str = "float32"  # microbatch accumulator dtype
 

@@ -13,6 +13,10 @@ depth while representing every assigned family:
 Decode threads per-layer caches through the same scans (cache stacks are
 the scanned xs/ys; the hidden state is the carry).
 
+The layer scan is checkpointed under ``cfg.remat_policy``; its default
+``auto`` keeps each layer's matmul outputs for the backward where they
+fit the device's memory (:meth:`Model.remat_for`), else recomputes them.
+
 Each layer runs under a ``jax.named_scope`` of
 :data:`repro.obs.DEVICE_SCOPES` (``embed``, ``attention``/``ssm``,
 ``mlp``/``moe``, ``head``; ``ssm`` holds the SSD scan's ``ssd``), which
@@ -24,7 +28,8 @@ and the head are fed in the compute dtype.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+import math
+from dataclasses import dataclass, field
 from functools import partial
 
 import jax
@@ -36,10 +41,34 @@ from . import ssm as ssm_mod
 from .config import ModelConfig
 from .layers import ACT_DTYPE, cross_entropy, embed_lookup, init_linear, rmsnorm
 
-__all__ = ["Model", "build_model", "segments_of"]
+__all__ = ["Model", "build_model", "segments_of", "device_bytes_limit",
+           "SAVED_DOTS_SHARE"]
 
 # block kind's mixer -> its device scope (repro.obs.DEVICE_SCOPES)
 _MIXER_SCOPE = {"attn": "attention", "mamba": "ssm"}
+
+# checkpoint policy of the layer scan -> what its backward reads from
+# the forward instead of recomputing ("none": no checkpoint at all)
+_REMAT_POLICIES = {
+    "nothing": jax.checkpoint_policies.nothing_saveable,
+    # keep matmul outputs; recompute only cheap elementwise
+    "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+}
+
+# The most of a device's memory that ``remat_policy="auto"`` lets the
+# layer scan keep as matmul outputs. The rest holds what the step needs
+# under either policy: the training state (16 bytes a parameter: bf16
+# weights and micro-gradient, the fp32 accumulator, Adam's m and v) and
+# the step's working set, and XLA's temporaries grow by up to about the
+# kept bytes again when the outputs are kept.
+SAVED_DOTS_SHARE = 0.25
+
+
+def device_bytes_limit() -> int | None:
+    """Bytes of memory the first local device lets this process use, or
+    None where its backend reports no limit (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
 
 
 def segments_of(cfg: ModelConfig) -> list[tuple[tuple[str, ...], int]]:
@@ -198,6 +227,9 @@ class Model:
     ep_axis: str = "model"
     attn_chunk: int = 1024
     partitioned: bool = False
+    # (batch, seq) -> saved_dot_bytes, filled at trace time
+    _saved_dots: dict = field(default_factory=dict, init=False,
+                              repr=False, compare=False)
 
     def flash_layers(self, seq: int) -> int:
         """How many attention layers of a forward over ``seq`` positions
@@ -220,6 +252,68 @@ class Model:
         s = self.cfg.ssm
         return ssm_mod.ssd_blocks(batch, seq, s.n_heads(self.cfg.d_model),
                                   min(s.chunk, seq))
+
+    def saved_dot_bytes(self, batch: int, seq: int) -> int:
+        """Bytes the ``dots`` policy keeps for the backward across every
+        scanned layer of one micro-batch of ``batch`` sequences of
+        ``seq`` positions: the residuals of each checkpointed layer body
+        under it, less those under ``nothing`` (the layer's input and
+        parameters). Per device where the model's mesh shards the
+        batch."""
+        key = (batch, seq)
+        if key not in self._saved_dots:
+            total = sum(
+                n_rep * (self._residual_bytes(pattern, batch, seq, "dots")
+                         - self._residual_bytes(pattern, batch, seq,
+                                                "nothing"))
+                for pattern, n_rep in segments_of(self.cfg))
+            axes = self._batch_axes(batch)
+            if axes is not None:
+                total //= math.prod(self.mesh.shape[a] for a in axes)
+            self._saved_dots[key] = total
+        return self._saved_dots[key]
+
+    def _residual_bytes(self, pattern, batch: int, seq: int,
+                        policy: str) -> int:
+        """Bytes of the residuals one layer of ``pattern`` hands its
+        backward under ``policy``, from shapes alone."""
+        cfg = self.cfg
+        x = jax.ShapeDtypeStruct(
+            (batch, seq, cfg.d_model),
+            jnp.float32 if cfg.residual_fp32 else ACT_DTYPE)
+        layer_p = jax.eval_shape(
+            lambda k: tuple(_init_block(kk, kind, cfg) for kk, kind in
+                            zip(jax.random.split(k, len(pattern)), pattern)),
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+        def residuals(x, layer_p):
+            positions = jnp.broadcast_to(jnp.arange(seq)[None], (batch, seq))
+            body = jax.checkpoint(self._layer(pattern, positions),
+                                  policy=_REMAT_POLICIES[policy])
+            return jax.vjp(body, x, layer_p)[1]
+
+        return sum(r.size * r.dtype.itemsize for r in
+                   jax.tree.leaves(jax.eval_shape(residuals, x, layer_p)))
+
+    def remat_for(self, batch: int, seq: int) -> str:
+        """Checkpoint policy of the layer scan for a micro-batch of
+        ``batch`` sequences of ``seq`` positions (the device-local shapes
+        under ``shard_map``): ``none`` without remat, else
+        ``cfg.remat_policy`` unless it is ``auto``. ``auto`` keeps the
+        matmul outputs (``dots``) where :meth:`saved_dot_bytes` is at
+        most :data:`SAVED_DOTS_SHARE` of the device's memory, and
+        recomputes them (``nothing``) where it is more or the device
+        reports no limit (the CPU)."""
+        cfg = self.cfg
+        if not cfg.remat:
+            return "none"
+        if cfg.remat_policy != "auto":
+            return cfg.remat_policy
+        limit = device_bytes_limit()
+        if limit is None or (self.saved_dot_bytes(batch, seq)
+                             > SAVED_DOTS_SHARE * limit):
+            return "nothing"
+        return "dots"
 
     # ---------------- sharding constraints ---------------- #
     def _batch_axes(self, batch: int):
@@ -430,6 +524,16 @@ class Model:
             return self._mask_pad(jnp.dot(x, head))
 
     # ---------------- forward / loss ---------------- #
+    def _layer(self, pattern, positions):
+        """One step of a segment's layer scan: ``(x, layer params) ->
+        x`` through the blocks of ``pattern``."""
+        def layer(xc, layer_p):
+            layer_p = self._pin_layer_grads(layer_p)
+            for kind, bp in zip(pattern, layer_p):
+                xc = self._block_forward(xc, bp, kind, positions)
+            return self._constrain(xc)
+        return layer
+
     def forward(self, params: dict, tokens: jax.Array | None = None,
                 embeds: jax.Array | None = None) -> jax.Array:
         """Training forward. Returns logits (B, S, V)."""
@@ -438,21 +542,12 @@ class Model:
         b, s = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
+        policy = self.remat_for(b, s)
         for (pattern, n_rep), seg in zip(segments_of(cfg), params["segments"]):
-            def body(xc, layer_p):
-                layer_p = self._pin_layer_grads(layer_p)
-                for kind, bp in zip(pattern, layer_p):
-                    xc = self._block_forward(xc, bp, kind, positions)
-                return self._constrain(xc), None
-            if cfg.remat and cfg.remat_policy != "none":
-                policy = {
-                    "nothing": jax.checkpoint_policies.nothing_saveable,
-                    # keep matmul outputs; recompute only cheap elementwise
-                    "dots": jax.checkpoint_policies.
-                    dots_with_no_batch_dims_saveable,
-                }[cfg.remat_policy]
-                body = jax.checkpoint(body, policy=policy)
-            x, _ = jax.lax.scan(body, x, seg)
+            layer = self._layer(pattern, positions)
+            if policy != "none":
+                layer = jax.checkpoint(layer, policy=_REMAT_POLICIES[policy])
+            x, _ = jax.lax.scan(lambda xc, p: (layer(xc, p), None), x, seg)
 
         logits = self._head(params, x)
         return self._constrain(logits, None, "model")

@@ -37,6 +37,7 @@ paths scale to the production mesh — the dry-run lowers exactly this
 """
 from __future__ import annotations
 
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -190,6 +191,7 @@ class SpareTrainer:
         self._step_fn = make_train_step(self.model, base_lr=base_lr,
                                         total_steps=total_steps)
         self._jitted: dict[int, Any] = {}       # S_A -> compiled step
+        self._remat_reported = False            # policy printed yet
         self.ckpt = None
         if ckpt_dir is not None:
             self.ckpt = CheckpointManager(
@@ -221,23 +223,43 @@ class SpareTrainer:
             self._count_compile(report)
         return self._jitted[s_a]
 
+    def _micro_rows(self) -> int:
+        """Sequences in one micro-batch of the step, as the model's
+        forward sees it."""
+        return self.state.n * self.pipeline.per_type_batch
+
     def _count_compile(self, report: TrainReport | None) -> None:
         """Account one new step program: the run's and the telemetry's
         recompile counts, how many attention layers it runs through
-        the flash kernel (``train.attention_kernel_layers``), and how many
+        the flash kernel (``train.attention_kernel_layers``), how many
         blocks each SSD call of its micro-batches runs its chunks in
-        (``train.ssd_chunk_blocks``, 0 with no SSM layer)."""
+        (``train.ssd_chunk_blocks``, 0 with no SSM layer), and what its
+        layer scan keeps for the backward: the bytes of matmul outputs
+        (``train.remat_saved_bytes``) and the layers that keep them
+        (``train.remat_dots_layers``), both 0 unless the policy is
+        ``dots``. The policy is printed once, with the first program."""
         if report is not None:
             report.recompiles += 1
+        rows, seq = self._micro_rows(), self.pipeline.seq
+        policy = self.model.remat_for(rows, seq)
+        dots = policy == "dots"
+        saved = self.model.saved_dot_bytes(rows, seq) if dots else 0
+        if not self._remat_reported:
+            print(f"[trainer] layer remat policy {policy!r} "
+                  f"(cfg {self.cfg.remat_policy!r}) for micro-batches of "
+                  f"{rows} x {seq}: {saved} bytes of matmul outputs kept",
+                  file=sys.stderr, flush=True)
+            self._remat_reported = True
         tel = self.telemetry
         if tel is not None:
             tel.counter("train.recompiles").inc()
             tel.gauge("train.attention_kernel_layers").set(
-                self.model.flash_layers(self.pipeline.seq))
+                self.model.flash_layers(seq))
             tel.gauge("train.ssd_chunk_blocks").set(
-                self.model.ssd_chunk_blocks(
-                    self.state.n * self.pipeline.per_type_batch,
-                    self.pipeline.seq))
+                self.model.ssd_chunk_blocks(rows, seq))
+            tel.gauge("train.remat_saved_bytes").set(saved)
+            tel.gauge("train.remat_dots_layers").set(
+                self.cfg.n_layers if dots else 0)
 
     def _step_batch(self, state, step: int) -> dict:
         """Step ``step``'s batch under ``state``'s schedule, on device."""

@@ -188,20 +188,33 @@ def _trainer(tel, seq=32):
 def test_attention_kernel_layers_gauge(monkeypatch):
     """The gauge reads 0 on the CPU trainer's step; with the TPU's choice
     it counts the model's attention layers (2 here); the trainer without
-    telemetry never asks."""
+    telemetry never asks. The layer scan's remat gauges read 0 on the
+    CPU, which reports no memory limit; with a chip's limit the step
+    keeps its matmul outputs in both layers, and reports their bytes."""
+    import repro.models.model as model_mod
     from repro.models.model import Model
     from repro.obs.trace import Telemetry
 
     tel = Telemetry(trace=False)
     tr = _trainer(tel)
     tr.run(1)
-    assert tel.snapshot()["gauges"]["train.attention_kernel_layers"] == 0
+    gauges = tel.snapshot()["gauges"]
+    assert gauges["train.attention_kernel_layers"] == 0
+    assert gauges["train.remat_saved_bytes"] == 0
+    assert gauges["train.remat_dots_layers"] == 0
 
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(model_mod, "device_bytes_limit",
+                        lambda: 16_900_000_000)
     tr = _trainer(Telemetry(trace=False), seq=512)
     tr._count_compile(None)
-    assert tr.telemetry.snapshot()["gauges"][
-        "train.attention_kernel_layers"] == 2
+    gauges = tr.telemetry.snapshot()["gauges"]
+    assert gauges["train.attention_kernel_layers"] == 2
+    assert gauges["train.remat_dots_layers"] == 2
+    # q, k, v, the out-projection, gate and up of 4 x 512 tokens in bf16
+    features = 2 * 128 + 2 * 128 + 256 + 2 * tr.cfg.d_ff
+    assert gauges["train.remat_saved_bytes"] == \
+        tr.model.saved_dot_bytes(4, 512) == features * 2 * 4 * 512 * 2
 
     def asked(self, seq):
         raise AssertionError("flash_layers called without telemetry")

@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 V5E_HBM_BYTES = 16 * 2 ** 30
+V5E_BYTES_LIMIT = 16_900_000_000     # a v5e chip's memory_stats() limit
 
 
 @pytest.fixture(scope="module")
@@ -62,6 +63,13 @@ def _compiled_step(cfg, one_chip, stacks: int, seq: int):
                    donate_argnums=(0, 1))
     return step.lower(on_chip(params), on_chip(opt),
                       on_chip(batch)).compile()
+
+
+def _chip_memory(mp) -> None:
+    """Let the program see a v5e chip's memory limit, which the CPU
+    device it runs on here does not report."""
+    import repro.models.model as model_mod
+    mp.setattr(model_mod, "device_bytes_limit", lambda: V5E_BYTES_LIMIT)
 
 
 def _peak_bytes(compiled) -> int:
@@ -204,7 +212,8 @@ def test_flash_attention_fwd_bwd_compile_for_v5e(one_chip, monkeypatch):
 def cell_step(one_chip):
     """The training cell's step (qwen2.5-3b widths, 4 layers, S_A = 2
     stacks of N=4 groups of one 1024-token sequence), compiled for v5e
-    with the attention kernel chosen as on the chip."""
+    with the attention kernel and the layer scan's remat policy chosen
+    as on the chip."""
     from repro.configs import get_config
     from repro.models import build_model
 
@@ -213,7 +222,9 @@ def cell_step(one_chip):
     cfg = get_config("qwen2.5-3b").scaled(n_layers=4, grad_accum=1)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "on_tpu", lambda: True)
+        _chip_memory(mp)
         assert build_model(cfg).flash_layers(1024) == 4
+        assert build_model(cfg).remat_for(4, 1024) == "dots"
         return _compiled_step(cfg, one_chip, stacks=2, seq=1024)
 
 
@@ -232,11 +243,15 @@ def test_cell_step_with_flash_attention_fits_one_v5e(cell_step):
 def mamba_cell_step(one_chip):
     """The Mamba-2 training cell's step (mamba2-1.3b widths, 12 layers,
     S_A = 2 stacks of N=4 groups of one 2048-token sequence), compiled
-    for v5e."""
+    for v5e with the layer scan's remat policy chosen as on the chip."""
     from repro.configs import get_config
+    from repro.models import build_model
 
     cfg = get_config("mamba2-1.3b").scaled(n_layers=12, grad_accum=1)
-    return _compiled_step(cfg, one_chip, stacks=2, seq=2048)
+    with pytest.MonkeyPatch.context() as mp:
+        _chip_memory(mp)
+        assert build_model(cfg).remat_for(4, 2048) == "dots"
+        return _compiled_step(cfg, one_chip, stacks=2, seq=2048)
 
 
 def test_mamba_cell_step_fits_one_v5e(mamba_cell_step):
